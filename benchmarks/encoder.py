@@ -16,7 +16,7 @@ import jax.numpy as jnp
 def encoder_bench():
     # deferred: benchmarks.run imports this module inside main(), so a
     # module-level import back into run would create an import cycle
-    from benchmarks.run import _timeit
+    from benchmarks.run import _kernel_tag, _timeit
     from repro.codec.motion import block_sad, block_sad_scan
     from repro.codec.video_codec import (VideoCodecConfig, encode_chunk,
                                          encode_chunk_batched)
@@ -42,9 +42,8 @@ def encoder_bench():
     us_v = _timeit(lambda: vmapped(cur, ref), n=3)
     rows.append((f"encoder_block_sad_vmapped_{H}x{W}", us_v,
                  f"vs_scan:{us_scan / max(us_v, 1e-9):.2f}x"))
-    us_k = _timeit(lambda: motion_sad(cur, ref, radius=radius,
-                                      interpret=True), n=3)
-    rows.append((f"encoder_block_sad_kernel_interp_{H}x{W}", us_k,
+    us_k = _timeit(lambda: motion_sad(cur, ref, radius=radius), n=3)
+    rows.append((f"encoder_block_sad_kernel_{_kernel_tag()}_{H}x{W}", us_k,
                  f"vs_scan:{us_scan / max(us_k, 1e-9):.2f}x;"
                  f"vs_fallback:{us_v / max(us_k, 1e-9):.2f}x"))
     vm_bf = jax.jit(lambda c, r: block_sad(c, r, radius,
@@ -53,9 +52,9 @@ def encoder_bench():
     rows.append((f"encoder_block_sad_vmapped_bf16_{H}x{W}", us_vbf,
                  f"vs_f32:{us_v / max(us_vbf, 1e-9):.2f}x"))
     us_kbf = _timeit(lambda: motion_sad(cur, ref, radius=radius,
-                                        interpret=True,
                                         dtype=jnp.bfloat16), n=3)
-    rows.append((f"encoder_block_sad_kernel_bf16_interp_{H}x{W}", us_kbf,
+    rows.append((f"encoder_block_sad_kernel_bf16_{_kernel_tag()}_{H}x{W}",
+                 us_kbf,
                  f"vs_f32:{us_k / max(us_kbf, 1e-9):.2f}x;"
                  f"vs_fallback:{us_vbf / max(us_kbf, 1e-9):.2f}x"))
 
@@ -68,9 +67,10 @@ def encoder_bench():
     rows.append((f"encoder_block_sad_diamond_{H}x{W}", us_d,
                  f"{evals};vs_exhaustive:{us_v / max(us_d, 1e-9):.2f}x"))
     us_dk = _timeit(lambda: motion_sad(cur, ref, radius=radius,
-                                       interpret=True, search="diamond"),
+                                       search="diamond"),
                     n=3)
-    rows.append((f"encoder_block_sad_kernel_diamond_interp_{H}x{W}", us_dk,
+    rows.append((f"encoder_block_sad_kernel_diamond_{_kernel_tag()}_"
+                 f"{H}x{W}", us_dk,
                  f"{evals};vs_scan:{us_scan / max(us_dk, 1e-9):.2f}x;"
                  f"vs_fallback:{us_d / max(us_dk, 1e-9):.2f}x"))
 

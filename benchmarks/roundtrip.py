@@ -207,8 +207,9 @@ def roundtrip_roi_bench():
     return rows
 
 
-def main():
-    """Forced-multi-device entry: sharded vs single-device round trip."""
+def sharded_rows():
+    """Sharded vs single-device round-trip rows on this process's
+    devices."""
     from benchmarks.run import SMOKE, _timeit
     from repro.core.roundtrip import RoundtripConfig, roundtrip_batched
     from repro.distributed.sharding import SINGLE_POD_RULES
@@ -242,7 +243,11 @@ def main():
          f"fps:{fps:.0f};vs_single:"
          f"{us_single / max(us_sharded, 1e-9):.2f}x"],
     ]
-    print(json.dumps(rows))
+    return rows
+
+
+def main():
+    print(json.dumps(sharded_rows()))
 
 
 if __name__ == "__main__":

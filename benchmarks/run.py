@@ -65,6 +65,13 @@ def _timeit(fn, *args, n=3, warmup=1):
     return (time.perf_counter() - t0) / n * 1e6
 
 
+def _kernel_tag() -> str:
+    """Row-name tag of the Pallas kernel rows: the ops wrappers interpret
+    on CPU and compile the kernel on TPU (no row forces interpret mode
+    on a chip)."""
+    return "tpu" if jax.default_backend() == "tpu" else "interp"
+
+
 def kernel_microbench():
     rows = []
     from repro.kernels.flash_attention.ops import flash_attention
@@ -74,17 +81,18 @@ def kernel_microbench():
     q = jax.random.normal(ks[0], (1, 256, 4, 64), jnp.float32)
     k = jax.random.normal(ks[1], (1, 256, 2, 64), jnp.float32)
     v = jax.random.normal(ks[2], (1, 256, 2, 64), jnp.float32)
-    us = _timeit(lambda: flash_attention(q, k, v, interpret=True), n=2)
-    rows.append(("kernel_flash_attention_interp", us, "B1S256H4D64"))
+    us = _timeit(lambda: flash_attention(q, k, v), n=2)
+    rows.append((f"kernel_flash_attention_{_kernel_tag()}", us,
+                 "B1S256H4D64"))
     anchor = jax.random.uniform(ks[0], (64, 96), jnp.float32) * 255
     mv = jax.random.randint(ks[1], (4, 6, 2), -8, 9, jnp.int32)
     resid = jnp.zeros((64, 96), jnp.float32)
-    us = _timeit(lambda: qtransfer(anchor, mv, resid, interpret=True), n=2)
-    rows.append(("kernel_qtransfer_interp", us, "64x96"))
+    us = _timeit(lambda: qtransfer(anchor, mv, resid), n=2)
+    rows.append((f"kernel_qtransfer_{_kernel_tag()}", us, "64x96"))
     blocks = jax.random.uniform(ks[2], (256, 8, 8), jnp.float32) * 255 - 128
-    us = _timeit(lambda: blockdct_quantize(blocks, 50.0, interpret=True),
+    us = _timeit(lambda: blockdct_quantize(blocks, 50.0),
                  n=2)
-    rows.append(("kernel_blockdct_interp", us, "256blocks"))
+    rows.append((f"kernel_blockdct_{_kernel_tag()}", us, "256blocks"))
     from repro.codec.motion import block_sad, block_sad_scan
     from repro.kernels.motion_sad.ops import motion_sad
     cur = jax.random.uniform(ks[0], (64, 96), jnp.float32) * 255
@@ -95,13 +103,13 @@ def kernel_microbench():
     us_scan = _timeit(lambda: scan(cur, ref), n=2)
     fb = jax.jit(lambda c, r: block_sad(c, r, 8))
     us_fb = _timeit(lambda: fb(cur, ref), n=2)
-    us = _timeit(lambda: motion_sad(cur, ref, radius=8, interpret=True), n=2)
-    rows.append(("kernel_motion_sad_interp", us,
+    us = _timeit(lambda: motion_sad(cur, ref, radius=8), n=2)
+    rows.append((f"kernel_motion_sad_{_kernel_tag()}", us,
                  f"64x96r8;vs_scan:{us_scan / max(us, 1e-9):.2f}x;"
                  f"vs_fallback:{us_fb / max(us, 1e-9):.2f}x"))
-    us_d = _timeit(lambda: motion_sad(cur, ref, radius=8, interpret=True,
+    us_d = _timeit(lambda: motion_sad(cur, ref, radius=8,
                                       search="diamond"), n=2)
-    rows.append(("kernel_motion_sad_diamond_interp", us_d,
+    rows.append((f"kernel_motion_sad_diamond_{_kernel_tag()}", us_d,
                  f"64x96r8;evals:37/289;"
                  f"vs_exhaustive_kernel:{us / max(us_d, 1e-9):.2f}x"))
     return rows
@@ -124,12 +132,13 @@ def realistic_shape_bench():
     scan = jax.jit(lambda c, r: block_sad_scan(c, r, 8))
     us_scan = _timeit(lambda: scan(cur, ref), n=2)
     rows.append((f"motion_sad_scan_{tag}", us_scan, "r8scan289cand"))
-    us = _timeit(lambda: motion_sad(cur, ref, radius=8, interpret=True), n=2)
-    rows.append((f"kernel_motion_sad_interp_{tag}", us,
+    us = _timeit(lambda: motion_sad(cur, ref, radius=8), n=2)
+    rows.append((f"kernel_motion_sad_{_kernel_tag()}_{tag}", us,
                  f"r8band;vs_scan:{us_scan / max(us, 1e-9):.2f}x"))
-    us_d = _timeit(lambda: motion_sad(cur, ref, radius=8, interpret=True,
+    us_d = _timeit(lambda: motion_sad(cur, ref, radius=8,
                                       search="diamond"), n=2)
-    rows.append((f"kernel_motion_sad_diamond_interp_{tag}", us_d,
+    rows.append((f"kernel_motion_sad_diamond_{_kernel_tag()}_{tag}",
+                 us_d,
                  f"r8;evals:37/289;"
                  f"vs_exhaustive_kernel:{us / max(us_d, 1e-9):.2f}x"))
     # static diamond dispatch at a realistic block count (720p = 3600
@@ -150,8 +159,8 @@ def realistic_shape_bench():
                  f"vs_fallback:{us_fbd / max(us_disp, 1e-9):.2f}x"))
     mv = jax.random.randint(ks[1], (H // 16, W // 16, 2), -8, 9, jnp.int32)
     resid = jnp.zeros((H, W), jnp.float32)
-    us = _timeit(lambda: qtransfer(cur, mv, resid, interpret=True), n=2)
-    rows.append((f"kernel_qtransfer_interp_{tag}", us,
+    us = _timeit(lambda: qtransfer(cur, mv, resid), n=2)
+    rows.append((f"kernel_qtransfer_{_kernel_tag()}_{tag}", us,
                  f"{H // 16}x{W // 16}blocks"))
     return rows
 
@@ -242,19 +251,23 @@ def _forced_cpu_env(n_devices: int = 4) -> dict:
     return env
 
 
-def _json_rows_subprocess(module: str, fallback_name: str):
-    """Run a bench module in a subprocess (forced 4-device CPU platform if
-    this process sees fewer than 4 devices — XLA only honours the
-    device-count flag before the first jax import) and parse the JSON row
-    payload from its last stdout line.  On a machine with real
-    accelerators the child inherits them instead (the flag only affects
-    the host platform)."""
+def _multidevice_rows(module: str, fallback_name: str):
+    """Rows of a multi-device bench module (its ``sharded_rows``).
+
+    Where this process already sees >= 4 devices (a 4-chip host, or a
+    forced CPU platform) the bench runs in-process: a child started now
+    could not get a chip this process holds.  Otherwise it runs in a
+    child pinned to a forced 4-device CPU platform (XLA only honours the
+    device-count flag before the first jax import), which never needs a
+    chip; its JSON rows come back on the last stdout line."""
+    if len(jax.devices()) >= 4:
+        import importlib
+        return [tuple(r) for r in
+                importlib.import_module(module).sharded_rows()]
     import subprocess
-    env = os.environ if not (jax.default_backend() == "cpu"
-                             and len(jax.devices()) < 4) \
-        else _forced_cpu_env()
     r = subprocess.run([sys.executable, "-m", module],
-                       capture_output=True, text=True, env=env, timeout=900)
+                       capture_output=True, text=True, env=_forced_cpu_env(),
+                       timeout=900)
     if r.returncode != 0:
         tail = (r.stderr or r.stdout).strip().replace("\n", ";")[-160:]
         return [(fallback_name, -1.0, f"ERROR:{tail}")]
@@ -264,14 +277,14 @@ def _json_rows_subprocess(module: str, fallback_name: str):
 def stream_sharding_bench():
     """Sharded-vs-single-device stream throughput (ROADMAP multi-host
     sharding item)."""
-    return _json_rows_subprocess("benchmarks.stream_shard",
+    return _multidevice_rows("benchmarks.stream_shard",
                                  "stream_sharding_bench")
 
 
 def roundtrip_sharding_bench():
     """Mesh-sharded fused round trip vs the single-device batched jit
     (``benchmarks.roundtrip`` main, forced multi-device child)."""
-    return _json_rows_subprocess("benchmarks.roundtrip",
+    return _multidevice_rows("benchmarks.roundtrip",
                                  "roundtrip_sharding_bench")
 
 
@@ -298,7 +311,8 @@ def main() -> None:
         SMOKE = True
         os.environ["BISWIFT_BENCH_SMOKE"] = "1"
     # --multidevice: re-run the whole harness on a forced 4-device CPU
-    # platform (fresh process; jax in THIS one is already committed)
+    # platform.  Nothing above has started a jax backend, so this process
+    # holds no chip, and the child is pinned to the CPU anyway.
     if "--multidevice" in sys.argv \
             and os.environ.get("BISWIFT_MULTIDEVICE_CHILD") != "1":
         import subprocess
@@ -307,6 +321,8 @@ def main() -> None:
         sys.exit(subprocess.run(
             [sys.executable, "-m", "benchmarks.run"], env=env).returncode)
 
+    from repro.launch.compile_cache import configure_compile_cache
+    configure_compile_cache()
     print("name,us_per_call,derived")
     all_rows = []
     t0 = time.time()
@@ -346,12 +362,11 @@ def main() -> None:
     with open(BENCH_JSON, "w") as f:
         json.dump(payload, f, indent=1)
     print(f"# wrote {BENCH_JSON} ({len(all_rows)} rows)")
-    if SMOKE and errors:
-        # the smoke gate EXISTS to catch import/trace breakage — an ERROR
-        # row swallowed into a green exit would defeat it (the full
-        # harness stays permissive so one flaky bench can't kill a run)
-        sys.exit(f"# smoke FAILED: {len(errors)} bench(es) errored: "
-                 f"{', '.join(errors)}")
+    if errors:
+        # every row is still written above; an ERROR row swallowed into a
+        # green exit would hide a broken bench, in smoke and full runs alike
+        sys.exit(f"# {'smoke ' if SMOKE else ''}FAILED: {len(errors)} "
+                 f"bench(es) errored: {', '.join(errors)}")
 
 
 if __name__ == "__main__":

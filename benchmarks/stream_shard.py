@@ -53,7 +53,8 @@ def build_inputs(n_streams):
     return args, params, det_cfg, T
 
 
-def main():
+def sharded_rows():
+    """Sharded-vs-single-device rows on this process's devices."""
     from repro.core.hybrid_decoder import decode_execute_batched
     from repro.distributed.sharding import SINGLE_POD_RULES
     from repro.distributed.stream_sharding import (shard_streams,
@@ -89,7 +90,11 @@ def main():
         [f"stream_sharded_{n_shards}shard_{N_STREAMS}streams", us_sharded,
          f"fps:{fps:.0f};vs_single:{us_single / max(us_sharded, 1e-9):.2f}x"],
     ]
-    print(json.dumps(rows))
+    return rows
+
+
+def main():
+    print(json.dumps(sharded_rows()))
 
 
 if __name__ == "__main__":

@@ -14,9 +14,9 @@ candidate order, first-wins tie-breaking):
   candidate loop slices inside those resident windows — no whole-frame
   copies, flat memory in the radius.
 * ``block_sad(use_kernel=True)`` — the Pallas TPU kernel in
-  ``repro.kernels.motion_sad`` (VMEM-resident padded reference, multiple
-  macroblock rows per grid step, candidates evaluated one dy-row chunk at
-  a time).
+  ``repro.kernels.motion_sad`` (multiple macroblock rows per grid step,
+  the ±R halo as extra row blocks, candidate windows read by rotating
+  the band one dy row and one dx lane at a time).
 
 ``search="diamond"`` — traced coarse-to-fine (three-step / diamond)
 search: a STATIC step schedule (``diamond_steps``: largest power of two

@@ -109,7 +109,7 @@ def decode_and_execute(packet: HybridPacket, detector_params, det_cfg,
 
     # pipeline ②: quality transfer (type-2 frames)
     residual_up = jax.vmap(lambda r: upscale_nearest(r[None], H, W)[0])(
-        _residual_px(enc))
+        _residual_px(enc.recon, enc.residual_q, enc.qtab))
     frames_exec = jnp.where((types == 1)[:, None, None],
                             jnp.asarray(packet.anchor_hd), lr_up)
     qt = _transfer(anchor_plane, jnp.asarray(anchor_idx, jnp.int32),
@@ -150,6 +150,30 @@ def anchor_index(types):
     return jnp.maximum(lax.cummax(marked), 0)
 
 
+def execution_planes(recon, mv, residual_q, qtab, types, anchor_hd,
+                     lr_extent=None):
+    """The frame each pipeline-①/② row runs the detector on, plus the
+    HD-grid motion vectors pipeline ③ shifts boxes with.
+
+    Type-1 frames take the decoded HD anchor; type-2 frames take the
+    upscaled LR frame quality-transferred from their nearest preceding
+    anchor (paper Fig. 7); type-3 frames carry the upscaled LR frame (not
+    inferred).  Shared by the fused decode (``_execute_chunk``) and the
+    serving runtime's stage step, so both serve the same pipelines.
+    Returns ((T, H, W) frames, (T, nby, nbx, 2) int32 MVs)."""
+    H, W = anchor_hd.shape[1:]
+    lr_up = upscale_nearest(recon, H, W, src_hw=lr_extent)
+    aidx = anchor_index(types)
+    mvs_hd = _upscale_mvs(mv, (H, W), lr_hw=lr_extent)
+    residual_up = jax.vmap(
+        lambda r: upscale_nearest(r[None], H, W, src_hw=lr_extent)[0])(
+        _residual_px(recon, residual_q, qtab))
+    frames_exec = jnp.where((types == 1)[:, None, None], anchor_hd, lr_up)
+    qt = _transfer(anchor_hd[aidx], aidx, mvs_hd, residual_up, frames_exec,
+                   types)
+    return qt, mvs_hd
+
+
 def _execute_chunk(enc, types, anchor_hd, gt_boxes, gt_valid,
                    detector_params, det_cfg, bw_kbps, queue_delay,
                    total_bits, costs: PipelineCosts, lr_extent=None,
@@ -170,19 +194,9 @@ def _execute_chunk(enc, types, anchor_hd, gt_boxes, gt_valid,
     run the convs, and a scatter with a temporal carry covers gated-off
     regions (bit-exact vs the ungated path when the gate admits every
     region — ``tests/test_roi.py``)."""
-    H, W = anchor_hd.shape[1:]
-
-    lr_up = upscale_nearest(enc.recon, H, W, src_hw=lr_extent)
-    aidx = anchor_index(types)
-    anchor_plane = anchor_hd[aidx]
-    mvs_hd = _upscale_mvs(enc.mv, (H, W), lr_hw=lr_extent)
-
-    residual_up = jax.vmap(
-        lambda r: upscale_nearest(r[None], H, W, src_hw=lr_extent)[0])(
-        _residual_px(enc))
-    frames_exec = jnp.where((types == 1)[:, None, None], anchor_hd, lr_up)
-    qt = _transfer(anchor_plane, aidx, mvs_hd, residual_up, frames_exec,
-                   types)
+    qt, mvs_hd = execution_planes(enc.recon, enc.mv, enc.residual_q,
+                                  enc.qtab, types, anchor_hd,
+                                  lr_extent=lr_extent)
 
     # pipelines ① + ② fused into one detector forward over the whole chunk
     # (ROI-gated onto the top-K packed patch batch when cfg carries a roi)
@@ -278,11 +292,10 @@ def decode_and_execute_fused(packet: HybridPacket, detector_params, det_cfg,
                        t_comp=float(out["t_comp"]))
 
 
-def _residual_px(enc):
+def _residual_px(recon, residual_q, qtab):
     from repro.core.quality_transfer import residual_to_pixels
-    h, w = enc.recon.shape[1:]
-    return jax.vmap(lambda q: residual_to_pixels(q, enc.qtab, h, w))(
-        enc.residual_q)
+    h, w = recon.shape[1:]
+    return jax.vmap(lambda q: residual_to_pixels(q, qtab, h, w))(residual_q)
 
 
 def _upscale_mvs(mv, hw, lr_hw=None):

@@ -244,7 +244,7 @@ def _downscale_pad(raw, levels):
     wp = max(w for _, w in shapes)
     lanes = []
     for s, level in enumerate(levels):
-        lr = downscale(raw[s], QUALITY_LADDER[level].scale)
+        lr = downscale(jnp.asarray(raw[s], f32), QUALITY_LADDER[level].scale)
         h, w = shapes[s]
         lanes.append(jnp.pad(lr, ((0, 0), (0, hp - h), (0, wp - w))))
     return jnp.stack(lanes)

@@ -42,9 +42,9 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.distributed.shard_map_compat import shard_map_compat
 from repro.distributed.sharding import AxisRules
 
 f32 = jnp.float32
@@ -78,15 +78,33 @@ def pad_stream_axis(tree, n_shards: int):
     """Zero-pad every leaf's leading (stream) axis to a multiple of
     ``n_shards``.  Zero lanes are safe: each stream's chunk computation is
     independent and guarded against degenerate inputs (bw floors at 1e-6,
-    F1 on empty boxes is finite), and the wrapper drops them on exit."""
+    F1 on empty boxes is finite), and the wrapper drops them on exit.
+    Host (numpy) leaves are padded on the host, so a large input can go
+    straight to its shards without first landing whole on one device."""
     def one(x):
-        x = jnp.asarray(x)
+        xp = np if isinstance(x, np.ndarray) else jnp
+        x = xp.asarray(x)
         s = x.shape[0]
         s_pad = -(-s // n_shards) * n_shards
         if s_pad == s:
             return x
         pad = [(0, s_pad - s)] + [(0, 0)] * (x.ndim - 1)
-        return jnp.pad(x, pad)
+        return xp.pad(x, pad)
+
+    return jax.tree.map(one, tree)
+
+
+def unpad_stream_axis(tree, s: int, mesh: Mesh):
+    """Drop padded stream lanes from a sharded result.  A slice that cuts
+    a sharded axis unevenly has no well-defined sharding (JAX refuses it
+    under an explicit-axes mesh), so a padded leaf is replicated over
+    ``mesh`` first; an unpadded leaf keeps its stream sharding."""
+    replicated = NamedSharding(mesh, P())
+
+    def one(x):
+        if x.shape[0] == s:
+            return x
+        return jax.device_put(x, replicated)[:s]
 
     return jax.tree.map(one, tree)
 
@@ -106,9 +124,9 @@ def shard_encode(mesh: Mesh, rules: AxisRules, *, cfg):
     spec = stream_partition_spec(mesh, rules)
     n_shards = stream_shard_count(mesh, rules)
 
-    sharded = jax.jit(shard_map_compat(
+    sharded = jax.jit(jax.shard_map(
         lambda f: _encode_batch(f, cfg), mesh=mesh,
-        in_specs=(spec,), out_specs=spec,
+        in_specs=(spec,), out_specs=spec, check_vma=False,
     ))
 
     def run(frames):
@@ -116,7 +134,7 @@ def shard_encode(mesh: Mesh, rules: AxisRules, *, cfg):
         s = frames.shape[0]
         (padded,) = pad_stream_axis((frames,), n_shards)
         out = sharded(padded)
-        return jax.tree.map(lambda x: x[:s], out)
+        return unpad_stream_axis(out, s, mesh)
 
     return run
 
@@ -146,16 +164,20 @@ def shard_roundtrip(mesh: Mesh, rules: AxisRules, *, cfg):
         return _roundtrip_ladder_body(raw, lr_pad, extents, qualities, gb,
                                       gv, params, t1, t2, bw, qd, cfg)
 
-    sharded = jax.jit(shard_map_compat(
+    sharded = jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(spec, spec, spec, spec, spec, spec, P(), spec, spec,
                   spec, spec),
-        out_specs=spec,
+        out_specs=spec, check_vma=False,
     ))
 
     def run(raw, gt_boxes, gt_valid, detector_params, *, tr1, tr2, bw_kbps,
             queue_delay=0.0, levels=None):
-        raw = jnp.asarray(raw, f32)
+        # host frames stay on the host until they go to their shards: a
+        # 9-stream 1080p chunk is 2.2 GB, too much to stage on one device
+        # next to that device's own share of the program
+        raw = np.asarray(raw, np.float32) if isinstance(raw, np.ndarray) \
+            else jnp.asarray(raw, f32)
         s = raw.shape[0]
         levels = tuple(levels) if levels is not None else (cfg.level,) * s
         lr_pad = _downscale_pad(raw, levels)
@@ -166,6 +188,7 @@ def shard_roundtrip(mesh: Mesh, rules: AxisRules, *, cfg):
                     jnp.broadcast_to(jnp.asarray(bw_kbps, f32), (s,)),
                     jnp.broadcast_to(jnp.asarray(queue_delay, f32), (s,)))
         r, lp, gb, gv, t1, t2, bw, qd = pad_stream_axis(streamed, n_shards)
+        r = jax.device_put(r, NamedSharding(mesh, spec))
         pad = r.shape[0] - s
         if pad:
             # padded lanes: full canvas extent, nominal quality
@@ -176,7 +199,7 @@ def shard_roundtrip(mesh: Mesh, rules: AxisRules, *, cfg):
                                                              f32)])
         out = sharded(r, lp, extents, qualities, gb, gv, detector_params,
                       t1, t2, bw, qd)
-        return jax.tree.map(lambda x: x[:s], out)
+        return unpad_stream_axis(out, s, mesh)
 
     return run
 
@@ -201,10 +224,10 @@ def shard_streams(mesh: Mesh, rules: AxisRules, *, det_cfg,
         return _execute_batch(e, ty, ah, gb, gv, params, det_cfg,
                               bw, qd, tb, costs)
 
-    sharded = jax.jit(shard_map_compat(
+    sharded = jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(spec, spec, spec, spec, spec, P(), spec, spec, spec),
-        out_specs=spec,
+        out_specs=spec, check_vma=False,
     ))
 
     def run(enc, types, anchor_hd, gt_boxes, gt_valid, detector_params, *,
@@ -217,6 +240,6 @@ def shard_streams(mesh: Mesh, rules: AxisRules, *, det_cfg,
                     jnp.broadcast_to(jnp.asarray(total_bits, f32), (s,)))
         e, ty, ah, gb, gv, bw, qd, tb = pad_stream_axis(streamed, n_shards)
         out = sharded(e, ty, ah, gb, gv, detector_params, bw, qd, tb)
-        return jax.tree.map(lambda x: x[:s], out)
+        return unpad_stream_axis(out, s, mesh)
 
     return run

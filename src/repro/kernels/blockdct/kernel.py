@@ -1,41 +1,39 @@
 """8×8 block DCT + quantize + dequant/IDCT kernel (Pallas TPU).
 
 The JPEG/codec transform core: y = D·x·Dᵀ, q = round(y / qtab),
-recon = Dᵀ·(q·qtab)·D.  Expressed as batched 8×8 matmuls over a VMEM tile
-of TILE blocks — MXU-shaped by construction (the (TILE·8, 8)×(8, 8)
-contractions keep the systolic array fed; the DCT matrix stays resident).
+recon = Dᵀ·(q·qtab)·D.  Each block is one row of 64 values (row-major
+flatten), so the separable 2-D transform is one matmul with the 64×64
+Kronecker matrix K = D ⊗ D: vec(D·x·Dᵀ) = K·vec(x), vec(Dᵀ·y·D) =
+Kᵀ·vec(y).  A (TILE, 64) × (64, 64) contraction is MXU-shaped and needs
+no 3-D reshape inside the kernel, which Mosaic cannot lay out.  The
+matmuls run at HIGHEST precision: the default bf16 passes would move
+quantized coefficients across rounding boundaries.
 
-Grid: (nb / TILE,).  VMEM per step: TILE·8·8·4 bytes ·3 ≈ 196 KiB at
-TILE = 256.
+Grid: (nb / TILE,).  VMEM per step: TILE·64·4 bytes ·3 ≈ 196 KiB at
+TILE = 256, plus the resident 64×64 matrix.
 """
 from __future__ import annotations
 
-
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 
 f32 = jnp.float32
 
 
-def _kernel(x_ref, d_ref, qt_ref, q_ref, rec_ref):
-    x = x_ref[...]                     # (TILE, 8, 8)
-    D = d_ref[...]                     # (8, 8)
-    qt = qt_ref[...]                   # (8, 8)
-    # y = D @ x @ D^T  via two batched contractions
-    y = jax.lax.dot_general(x, D.T, (((2,), (0,)), ((), ())),
-                            preferred_element_type=f32)     # x @ D^T
-    y = jax.lax.dot_general(D, y, (((1,), (1,)), ((), ())),
-                            preferred_element_type=f32)     # (8, TILE, 8)
-    y = y.transpose(1, 0, 2)                                # (TILE, 8, 8)
-    q = jnp.round(y / qt[None])
+def _dot(a, b):
+    return jnp.dot(a, b, precision=lax.Precision.HIGHEST,
+                   preferred_element_type=f32)
+
+
+def _kernel(x_ref, k_ref, qt_ref, q_ref, rec_ref):
+    x = x_ref[...]                     # (TILE, 64) row-major blocks
+    K = k_ref[...]                     # (64, 64) = D ⊗ D
+    qt = qt_ref[...]                   # (1, 64)
+    q = jnp.round(_dot(x, K.T) / qt)
     q_ref[...] = q.astype(q_ref.dtype)
-    deq = q * qt[None]
-    r = jax.lax.dot_general(deq, D, (((2,), (0,)), ((), ())),
-                            preferred_element_type=f32)     # deq @ D
-    r = jax.lax.dot_general(D.T, r, (((1,), (1,)), ((), ())),
-                            preferred_element_type=f32)
-    rec_ref[...] = r.transpose(1, 0, 2).astype(rec_ref.dtype)
+    rec_ref[...] = _dot(q * qt, K).astype(rec_ref.dtype)
 
 
 def blockdct_tiles(blocks, dmat, qtab, *, tile: int = 256,
@@ -44,27 +42,28 @@ def blockdct_tiles(blocks, dmat, qtab, *, tile: int = 256,
     nb = blocks.shape[0]
     tile = min(tile, nb)
     pad = (-nb) % tile
+    x = blocks.reshape(nb, 64)
     if pad:
-        blocks = jnp.concatenate(
-            [blocks, jnp.zeros((pad, 8, 8), blocks.dtype)], axis=0)
-    n = blocks.shape[0]
+        x = jnp.concatenate([x, jnp.zeros((pad, 64), x.dtype)], axis=0)
+    n = x.shape[0]
+    kron = jnp.kron(dmat, dmat).astype(f32)
 
     q, rec = pl.pallas_call(
         _kernel,
         grid=(n // tile,),
         in_specs=[
-            pl.BlockSpec((tile, 8, 8), lambda i: (i, 0, 0)),
-            pl.BlockSpec((8, 8), lambda i: (0, 0)),
-            pl.BlockSpec((8, 8), lambda i: (0, 0)),
+            pl.BlockSpec((tile, 64), lambda i: (i, 0)),
+            pl.BlockSpec((64, 64), lambda i: (0, 0)),
+            pl.BlockSpec((1, 64), lambda i: (0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((tile, 8, 8), lambda i: (i, 0, 0)),
-            pl.BlockSpec((tile, 8, 8), lambda i: (i, 0, 0)),
+            pl.BlockSpec((tile, 64), lambda i: (i, 0)),
+            pl.BlockSpec((tile, 64), lambda i: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n, 8, 8), blocks.dtype),
-            jax.ShapeDtypeStruct((n, 8, 8), blocks.dtype),
+            jax.ShapeDtypeStruct((n, 64), blocks.dtype),
+            jax.ShapeDtypeStruct((n, 64), blocks.dtype),
         ],
         interpret=interpret,
-    )(blocks, dmat, qtab)
-    return q[:nb], rec[:nb]
+    )(x, kron, qtab.reshape(1, 64).astype(f32))
+    return q[:nb].reshape(nb, 8, 8), rec[:nb].reshape(nb, 8, 8)

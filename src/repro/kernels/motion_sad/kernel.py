@@ -1,52 +1,48 @@
 """Block-motion SAD kernels (Pallas TPU): exhaustive full search and a
 traced coarse-to-fine (diamond / three-step) search.
 
-Tiling (reworked in the kernel speed pass): one grid step now produces
-MULTIPLE macroblock rows of the MV field.  ``_rows_per_step`` picks the
-largest row count whose resident working window — the row band plus its
-±R halo against the padded reference — stays inside ``_WINDOW_BUDGET``
-(512 KiB), which keeps the candidate loop L2-resident in interpret mode
-and leaves headroom under the ~16 MiB/core VMEM budget on TPU (the
-padded reference itself is staged whole via a constant index map:
-720p f32 padded by R=8 is (736, 1296) ≈ 3.6 MiB; use bf16 at 1080p).
-At small shapes (64×96) the whole frame is one grid step, so the
-per-step staging that used to dominate is paid exactly once.
+Layout.  Frames stay in their natural (rows, lanes) layout, padded on
+the host side to whole 128-lane tiles (``shift.padded_width``) and to
+whole grid steps.  One grid step owns ``rows`` macroblock rows: its
+current-frame band is one (rows·16, Wp) block, and its reference band is
+that block plus ``halo_blocks`` following blocks of the edge-padded
+reference (several BlockSpecs on the same array), so the ±R halo arrives
+through the ordinary pipeline with every DMA tile-aligned.  Candidate
+windows are read with rotations (``pltpu.roll``) and selects, never with
+an unaligned or dynamic-lane slice, which Mosaic refuses.
 
-Candidate evaluation is a flat ``fori_loop`` over all (2R+1)² offsets,
-but the per-candidate reduce runs in a two-stage row-major layout
-(``(bh, nbx, MB).sum(-1)`` then ``(rows, MB, nbx).sum(1)``) that XLA:CPU
-vectorizes far better than the oracle's two-strided-axis reduce — this,
-not the loop structure, is where the kernel's speed over the scan oracle
-comes from.  The two-stage sum can differ from the oracle's summation
-order by float-rounding ULPs on non-integer inputs, so it is used for
-*selection only*: after the loop each grid step recomputes the winning
-candidate's SAD once in the oracle's per-block reduction order, making
-the returned (mv, sad) bit-exact vs ``block_sad_scan``.  (On
-integer-valued content ≤ 2²⁴ — i.e. real video pixels — every summation
-order is exact, so even selection is provably order-independent there;
-for continuous inputs a selection flip would need two distinct residual
-patterns whose exact f32 sums collide in one order but not the other.)
+Per-block SADs.  ``|cur - cand|`` is summed over each macroblock's 16
+rows (a tile-aligned sublane reshape), then over its 16 lanes with an
+xor butterfly of lane rotations, which leaves every lane of a
+macroblock column holding that block's SAD.  All selection state lives
+in that (rows, Wp) lane layout; the wrapper reads lane 16·bx.
 
-Candidate order stays dy-major (idx = (dy+R)·(2R+1) + (dx+R)), identical
-to ``repro.codec.motion._offsets``, with a strict ``<`` best-update, so
+Exactness.  The in-kernel sums are used for SELECTION only.  On
+integer-valued content ≤ 2²⁴ — real 8-bit video — every f32 summation
+order is exact, so the selected candidate is provably the oracle's; on
+continuous inputs a flip would need two distinct residual patterns whose
+exact sums collide in one order but not the other.  The returned SAD is
+recomputed outside the kernel for the winning offset only, with the
+oracle's per-block reduction (``winner_sad``), so (mv, sad) are
+bit-exact vs ``block_sad_scan`` / ``block_sad_diamond``.
+
+Exhaustive search: a ``fori_loop`` over dy (one sublane rotation of the
+band per dy) with the 2R+1 dx candidates unrolled inside (one lane
+rotation each) — dy-major order, identical to
+``repro.codec.motion._offsets``, with a strict ``<`` best-update, so
 first-wins tie-breaking matches the scan oracle.
 
-``search="diamond"`` selects the traced coarse-to-fine kernel: a static
-step schedule (largest power of two ≤ R, halving to 1 — see
-``repro.codec.motion.diamond_steps``) probes a 3×3 neighbourhood around
-each macroblock's running best offset, clipped to ±R.  Every shape is
-static (the step count is baked into the trace), so the variant is
-jit-stable; it evaluates 1 + 9·len(steps) candidates per block instead of
-(2R+1)² and matches the pure-jnp fallback ``block_sad_diamond``
-bit-exactly on MVs.  Quality vs exhaustive is a documented tolerance
-contract (docs/fused_encoder.md), not bit-exactness.
+``search="diamond"``: the static step schedule of
+``repro.codec.motion.diamond_steps`` probes a 3×3 neighbourhood of each
+macroblock's running best offset, clipped to ±R.  Offsets differ per
+block, so each probe reads its window with ``shift.lane_shift`` /
+``shift.row_shift`` (per-lane amounts); the dx-shifted band is shared by
+the three probes of a column.  Same probe order, clip and first-wins as
+the fallback, so MVs are bit-exact vs ``block_sad_diamond``.
 
-``dtype=jnp.bfloat16`` selects the bf16 storage variant on both kernels:
-cur/ref bands are staged in VMEM as bf16 — halving the resident footprint
-and doubling effective bandwidth at 1080p — while every SAD accumulates
-in f32 inside the kernel.  The 16×W band blocks satisfy the bf16
-(16, 128) minimum tile (sublane 16 = MB; lane W is a multiple of 128 at
-ladder resolutions).
+``dtype=jnp.bfloat16`` stages cur/ref in bf16 (half the DMA bytes and
+VMEM); every SAD accumulates in f32 inside the kernel.  Band heights are
+multiples of 16 rows, the bf16 minimum tile.
 """
 from __future__ import annotations
 
@@ -56,104 +52,108 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.shift import (band, halo_blocks, lane_shift,
+                                 padded_width, row_shift)
 
 MB = 16
 f32 = jnp.float32
 
-# per-grid-step resident working window budget: the (rows*MB + 2R) ×
-# (W + 2R) reference band the candidate loop repeatedly re-reads.  512 KiB
-# keeps it L2-resident on CPU interpret runs and is far under VMEM on TPU.
-_WINDOW_BUDGET = 512 * 1024
+# macroblock rows per grid step of the exhaustive search: a 4-row band of
+# 1080p f32 is (64, 2048) = 512 KiB, well inside the scoped VMEM with
+# double buffering.  The diamond kernel steps one row at a time, since
+# its per-lane shifts scale with the band height.
+_EXHAUSTIVE_ROWS = 4
 
 
-def _rows_per_step(nby: int, width: int, radius: int, itemsize: int = 4,
-                   max_rows: int = 8) -> int:
-    """Largest macroblock-row count r ≤ max_rows dividing nby whose
-    resident reference window (r*MB + 2R, W + 2R) fits the budget."""
-    for r in range(min(nby, max_rows), 0, -1):
-        if nby % r:
-            continue
-        window = (r * MB + 2 * radius) * (width + 2 * radius) * itemsize
-        if window <= _WINDOW_BUDGET:
-            return r
-    return 1
+def _block_sums(d, rows: int, lane):
+    """(rows·16, Wp) per-pixel values -> (rows, Wp) per-macroblock sums,
+    each broadcast over the block's 16 lanes (xor butterfly)."""
+    width = d.shape[-1]
+    s = d.reshape(rows, MB, width).sum(axis=1)
+    for k in (1, 2, 4, 8):
+        lower = pltpu.roll(s, k, 1)            # s[l - k]
+        upper = pltpu.roll(s, width - k, 1)    # s[l + k]
+        s = s + jnp.where((lane & k) != 0, lower, upper)
+    return s
 
 
-def _gather_sad(band, curb, offy, offx, radius: int):
-    """Per-block SAD at per-block offsets, in the oracle's per-block
-    reduction order.  band: (bh + 2R, W + 2R) resident reference slab;
-    curb: (rows, nbx, MB, MB); offy/offx: (rows, nbx) int32 in [-R, R]."""
-    rows, nbx = curb.shape[:2]
-    base_y = (jnp.arange(rows, dtype=jnp.int32) * MB)[:, None]
-    base_x = (jnp.arange(nbx, dtype=jnp.int32) * MB)[None, :]
-    ar = jnp.arange(MB, dtype=jnp.int32)
-    ys = (base_y + offy + radius)[..., None] + ar     # (rows, nbx, MB)
-    xs = (base_x + offx + radius)[..., None] + ar
-    cand = band[ys[..., :, None], xs[..., None, :]]   # (rows, nbx, MB, MB)
-    return jnp.abs(curb - cand).sum(axis=(2, 3))
-
-
-def _kernel(cur_ref, refp_ref, sad_ref, idx_ref, *, radius: int, rows: int,
-            nbx: int, width: int):
-    i = pl.program_id(0)
+def _exhaustive_kernel(cur_ref, *refs, radius: int, rows: int):
+    *band_refs, sad_ref, idx_ref = refs
     side = 2 * radius + 1
     bh = rows * MB
-    cur = cur_ref[...].astype(f32)                        # (bh, W)
+    cur = cur_ref[...].astype(f32)                         # (bh, Wp)
+    ref = band(band_refs)                                  # (Hb, Wp)
+    height, width = ref.shape
+    lane = lax.broadcasted_iota(jnp.int32, (rows, width), 1)
 
-    def body(idx, carry):
+    def body(dy, carry):
         best_sad, best_idx = carry
-        dy, dx = idx // side, idx % side
-        win = refp_ref[pl.dslice(i * bh + dy, bh),
-                       pl.dslice(dx, width)].astype(f32)
-        d = jnp.abs(cur - win)
-        # two-stage row-major reduce: contiguous 16-wide inner sum, then
-        # the block-row sum — the layout XLA vectorizes.  Selection only;
-        # the winner's SAD is recomputed in oracle order below.
-        sad = d.reshape(bh, nbx, MB).sum(-1).reshape(rows, MB, nbx).sum(1)
-        better = sad < best_sad
-        return (jnp.where(better, sad, best_sad),
-                jnp.where(better, idx.astype(jnp.int32), best_idx))
+        rows_dy = pltpu.roll(ref, (height - dy) % height, 0)[:bh]
+        for dx in range(side):
+            win = rows_dy if dx == 0 else pltpu.roll(rows_dy, width - dx, 1)
+            sad = _block_sums(jnp.abs(cur - win), rows, lane)
+            better = sad < best_sad
+            best_sad = jnp.where(better, sad, best_sad)
+            best_idx = jnp.where(better, dy * side + dx, best_idx)
+        return best_sad, best_idx
 
-    init = (jnp.full((rows, nbx), jnp.inf, f32),
-            jnp.zeros((rows, nbx), jnp.int32))
-    best_sad, best_idx = lax.fori_loop(0, side * side, body, init)
-
-    # one oracle-order evaluation of the winning candidate per block, so
-    # the returned SAD is bit-exact vs block_sad_scan
-    band = refp_ref[pl.dslice(i * bh, bh + 2 * radius),
-                    pl.dslice(0, width + 2 * radius)].astype(f32)
-    curb = cur.reshape(rows, MB, nbx, MB).transpose(0, 2, 1, 3)
-    sad_ref[...] = _gather_sad(band, curb, best_idx // side - radius,
-                               best_idx % side - radius, radius)
-    idx_ref[...] = best_idx
+    init = (jnp.full((rows, width), jnp.inf, f32),
+            jnp.zeros((rows, width), jnp.int32))
+    best_sad, best_idx = lax.fori_loop(0, side, body, init)
+    sad_ref[0] = best_sad
+    idx_ref[0] = best_idx
 
 
-def _diamond_kernel(cur_ref, refp_ref, sad_ref, mv_ref, *, radius: int,
-                    rows: int, nbx: int, width: int, steps: tuple):
-    i = pl.program_id(0)
-    bh = rows * MB
-    cur = cur_ref[...].astype(f32)                        # (bh, W)
-    band = refp_ref[pl.dslice(i * bh, bh + 2 * radius),
-                    pl.dslice(0, width + 2 * radius)].astype(f32)
-    curb = cur.reshape(rows, MB, nbx, MB).transpose(0, 2, 1, 3)
+def _diamond_kernel(cur_ref, *refs, radius: int, steps: tuple):
+    *band_refs, sad_ref, mvy_ref, mvx_ref = refs
+    n = 2 * radius + 1
+    cur = cur_ref[...].astype(f32)                         # (16, Wp)
+    ref = band(band_refs)                                  # (Hb, Wp)
+    lane = lax.broadcasted_iota(jnp.int32, (1, ref.shape[1]), 1)
 
-    zero = jnp.zeros((rows, nbx), jnp.int32)
+    def sad_at(cols, oy):
+        cand = row_shift(cols, oy + radius, n, MB)
+        return _block_sums(jnp.abs(cur - cand), 1, lane)
+
+    zero = jnp.zeros((1, ref.shape[1]), jnp.int32)
     best_y, best_x = zero, zero
-    best_sad = _gather_sad(band, curb, zero, zero, radius)
+    best_sad = sad_at(lane_shift(ref, zero + radius, n), zero)
     # static unroll: len(steps) rounds of 9 probes, dy-major, first-wins
     for s in steps:
         cy, cx = best_y, best_x
+        ox = [jnp.clip(cx + px, -radius, radius) for px in (-s, 0, s)]
+        cols = [lane_shift(ref, o + radius, n) for o in ox]
         for py in (-s, 0, s):
-            for px in (-s, 0, s):
-                oy = jnp.clip(cy + py, -radius, radius)
-                ox = jnp.clip(cx + px, -radius, radius)
-                sad = _gather_sad(band, curb, oy, ox, radius)
+            oy = jnp.clip(cy + py, -radius, radius)
+            for j in range(3):
+                sad = sad_at(cols[j], oy)
                 better = sad < best_sad
                 best_sad = jnp.where(better, sad, best_sad)
                 best_y = jnp.where(better, oy, best_y)
-                best_x = jnp.where(better, ox, best_x)
-    sad_ref[...] = best_sad.astype(sad_ref.dtype)
-    mv_ref[...] = jnp.stack([best_y, best_x], axis=-1)
+                best_x = jnp.where(better, ox[j], best_x)
+    sad_ref[0] = best_sad
+    mvy_ref[0] = best_y
+    mvx_ref[0] = best_x
+
+
+def winner_sad(cur, ref, mv, radius: int, store=f32):
+    """SAD of each macroblock at its chosen offset, in the oracle's
+    per-block reduction order (``block_sad_scan`` / the fallbacks)."""
+    H, W = cur.shape
+    nby, nbx = H // MB, W // MB
+    refp = jnp.pad(ref.astype(store), radius, mode="edge").astype(f32)
+    curb = cur.astype(store).astype(f32).reshape(
+        nby, MB, nbx, MB).transpose(0, 2, 1, 3)
+    ar = jnp.arange(MB, dtype=jnp.int32)
+    ys = (jnp.arange(nby, dtype=jnp.int32) * MB)[:, None] + mv[..., 0] \
+        + radius
+    xs = (jnp.arange(nbx, dtype=jnp.int32) * MB)[None, :] + mv[..., 1] \
+        + radius
+    cand = refp[(ys[..., None] + ar)[..., :, None],
+                (xs[..., None] + ar)[..., None, :]]        # (nby, nbx, MB, MB)
+    return jnp.abs(curb - cand).sum(axis=(2, 3))
 
 
 def motion_sad_rows(cur, ref, *, radius: int = 8, interpret: bool = False,
@@ -162,53 +162,60 @@ def motion_sad_rows(cur, ref, *, radius: int = 8, interpret: bool = False,
 
     Returns (mv (nby, nbx, 2) int32, sad (nby, nbx) f32) — the codec
     convention pred(y) = ref(y + mv), matching ``repro.codec.motion``.
-    ``dtype`` is the VMEM storage dtype of the staged operands (bf16
-    halves the resident reference); SADs accumulate in f32 regardless.
-    ``search`` picks exhaustive ±R full search (bit-exact vs the scan
-    oracle) or the traced diamond search (subset of the candidate set,
-    quality-contract semantics).
+    ``dtype`` is the storage dtype of the staged operands (bf16 halves
+    the DMA bytes); SADs accumulate in f32 regardless.  ``search`` picks
+    exhaustive ±R full search (bit-exact vs the scan oracle) or the
+    traced diamond search (subset of the candidate set, quality-contract
+    semantics).
     """
+    if search not in ("exhaustive", "diamond"):
+        raise ValueError(f"unknown search strategy {search!r} "
+                         "(expected 'exhaustive' or 'diamond')")
     store = dtype or f32
     H, W = cur.shape
     nby, nbx = H // MB, W // MB
-    rows = _rows_per_step(nby, W, radius, jnp.dtype(store).itemsize)
+    rows = min(_EXHAUSTIVE_ROWS, nby) if search == "exhaustive" else 1
+    bh = rows * MB
+    n_steps = -(-nby // rows)
+    halo = halo_blocks(bh, 2 * radius)
+    width = padded_width(W, 2 * radius)
+
+    curp = jnp.pad(cur.astype(store),
+                   ((0, n_steps * bh - H), (0, width - W)))
     refp = jnp.pad(ref.astype(store), radius, mode="edge")
+    refp = jnp.pad(refp, ((0, (n_steps + halo) * bh - refp.shape[0]),
+                          (0, width - refp.shape[1])))
 
     if search == "exhaustive":
-        kernel = functools.partial(_kernel, radius=radius, rows=rows,
-                                   nbx=nbx, width=W)
-    elif search == "diamond":
+        kernel = functools.partial(_exhaustive_kernel, radius=radius,
+                                   rows=rows)
+        out_dtypes = (f32, jnp.int32)
+    else:
         from repro.codec.motion import diamond_steps
         kernel = functools.partial(_diamond_kernel, radius=radius,
-                                   rows=rows, nbx=nbx, width=W,
                                    steps=diamond_steps(radius))
-    else:
-        raise ValueError(f"unknown search strategy {search!r} "
-                         "(expected 'exhaustive' or 'diamond')")
+        out_dtypes = (f32, jnp.int32, jnp.int32)
 
-    out_specs = [pl.BlockSpec((rows, nbx), lambda i: (i, 0))]
-    out_shape = [jax.ShapeDtypeStruct((nby, nbx), f32)]
-    if search == "diamond":
-        out_specs.append(pl.BlockSpec((rows, nbx, 2), lambda i: (i, 0, 0)))
-        out_shape.append(jax.ShapeDtypeStruct((nby, nbx, 2), jnp.int32))
-    else:
-        out_specs.append(pl.BlockSpec((rows, nbx), lambda i: (i, 0)))
-        out_shape.append(jax.ShapeDtypeStruct((nby, nbx), jnp.int32))
-
-    sad, out = pl.pallas_call(
+    out_spec = pl.BlockSpec((1, rows, width), lambda i: (i, 0, 0))
+    outs = pl.pallas_call(
         kernel,
-        grid=(nby // rows,),
-        in_specs=[
-            pl.BlockSpec((rows * MB, W), lambda i: (i, 0)),
-            pl.BlockSpec((H + 2 * radius, W + 2 * radius), lambda i: (0, 0)),
-        ],
-        out_specs=out_specs,
-        out_shape=out_shape,
+        grid=(n_steps,),
+        in_specs=[pl.BlockSpec((bh, width), lambda i: (i, 0))] + [
+            pl.BlockSpec((bh, width), lambda i, j=j: (i + j, 0))
+            for j in range(halo + 1)],
+        out_specs=[out_spec] * len(out_dtypes),
+        out_shape=[jax.ShapeDtypeStruct((n_steps, rows, width), d)
+                   for d in out_dtypes],
         interpret=interpret,
-    )(cur.astype(store), refp)
+    )(curp, *([refp] * (halo + 1)))
+    # every lane of a macroblock column holds the block's value
+    outs = [o.reshape(n_steps * rows, width)[:nby, :W:MB] for o in outs]
 
-    if search == "diamond":
-        return out, sad
-    side = 2 * radius + 1
-    mv = jnp.stack([out // side - radius, out % side - radius], axis=-1)
-    return mv.astype(jnp.int32), sad
+    if search == "exhaustive":
+        side = 2 * radius + 1
+        idx = outs[1]
+        mv = jnp.stack([idx // side - radius, idx % side - radius], axis=-1)
+    else:
+        mv = jnp.stack(outs[1:], axis=-1)
+    mv = mv.astype(jnp.int32)
+    return mv, winner_sad(cur, ref, mv, radius, store)

@@ -1,8 +1,10 @@
 """Pure-jnp oracle for the qtransfer kernel.
 
-Same semantics as repro.codec.motion.warp_blocks + residual add, with the
-kernel's clamping rules (vertical clamp to ±radius, horizontal clamp to
-the frame border, edge padding).
+``repro.codec.motion.warp_blocks`` + residual add with the kernel's
+offset bound: offsets are clipped to ±radius and the anchor is
+edge-padded, so a block reaching past the frame reads edge-replicated
+pixels.  At radius 16 and |mv| <= 16 this is exactly
+``clip(warp_blocks(anchor, mv) + resid, 0, 255)``.
 """
 from __future__ import annotations
 
@@ -17,13 +19,12 @@ f32 = jnp.float32
 def qtransfer_ref(anchor, mv, resid, *, radius: int = 16):
     H, W = anchor.shape
     nby, nbx = mv.shape[:2]
-    ap = jnp.pad(anchor.astype(f32), ((radius, radius), (0, 0)), mode="edge")
+    ap = jnp.pad(anchor.astype(f32), radius, mode="edge")
+    mv = jnp.clip(mv, -radius, radius)
 
     def one(by, bx):
-        dy = jnp.clip(mv[by, bx, 0], -radius, radius)
-        dx = mv[by, bx, 1]
-        y0 = radius + by * MB + dy
-        x0 = jnp.clip(bx * MB + dx, 0, W - MB)
+        y0 = radius + by * MB + mv[by, bx, 0]
+        x0 = radius + bx * MB + mv[by, bx, 1]
         return lax.dynamic_slice(ap, (y0, x0), (MB, MB))
 
     rows = jax.vmap(lambda by: jax.vmap(lambda bx: one(by, bx))(

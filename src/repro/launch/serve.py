@@ -5,6 +5,11 @@ loop: synthetic cameras -> hybrid encoder -> (simulated) shared uplink ->
 edge runtime (3 pipelines, batched detector, admission control) ->
 bandwidth controller feedback.  This is deliverable (b)'s end-to-end
 serving driver; benchmarks/ reuse the same plumbing.
+
+``main`` returns a summary dict, and an ``on_chunk`` callback sees every
+served chunk (its packet, ground truth and results) — ``chip_smoke.py``
+drives the paper deployment through here and checks results that way.
+Weights and video are made from ``--seed``.
 """
 from __future__ import annotations
 
@@ -17,6 +22,7 @@ import numpy as np
 from repro.core.bandwidth_controller import BandwidthController, \
     even_proportions
 from repro.core.hybrid_encoder import encode_hybrid
+from repro.launch.compile_cache import configure_compile_cache
 from repro.models import detection as D
 from repro.serving.runtime import EdgeRuntime
 from repro.serving.scheduler import ServingConfig
@@ -25,7 +31,22 @@ from repro.sim.network import TraceConfig, allocate, generate_trace
 from repro.sim.video_source import paper_stream_mix, generate_chunk
 
 
-def main(argv=None):
+@jax.jit
+def _chunk_f1(boxes, scores, gt_boxes, gt_valid):
+    """(T,) per-frame F1 of NMS'd detections — one dispatch per chunk."""
+    def one(b, s, g, v):
+        return D.f1_score(*D.greedy_nms(b, s, iou_thresh=0.4, top_k=16),
+                          g, v)
+    return jax.vmap(one)(boxes, scores, gt_boxes, gt_valid)
+
+
+def main(argv=None, on_chunk=None) -> dict:
+    """Run the serving loop; returns the summary (also printed).
+
+    ``on_chunk(info)`` is called after each served chunk with a dict of
+    ``t``, ``stream``, ``packet``, ``gt_boxes``, ``gt_valid``,
+    ``bw_kbps``, the runtime's ``boxes``/``scores``/``types`` and the
+    detector ``params``/``det_cfg``."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--streams", type=int, default=4)
     ap.add_argument("--chunks", type=int, default=6)
@@ -37,11 +58,14 @@ def main(argv=None):
     ap.add_argument("--detector-ckpt", default=None)
     ap.add_argument("--quick-train", type=int, default=150,
                     help="inline detector fit steps when no ckpt (0=off)")
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seeds detector weights, quick-fit and video")
     args = ap.parse_args(argv)
+    configure_compile_cache()
 
     streams = paper_stream_mix(args.streams, args.height, args.width)
     det_cfg = D.TinyDetectorConfig()
-    params = D.init(jax.random.PRNGKey(1), det_cfg)
+    params = D.init(jax.random.PRNGKey(args.seed + 1), det_cfg)
     if args.detector_ckpt:
         from repro.train import checkpoint as CKPT
         step = CKPT.latest_step(args.detector_ckpt)
@@ -64,7 +88,7 @@ def main(argv=None):
             return params, opt, loss
 
         print(f"quick-training detector ({args.quick_train} steps)...")
-        kq = jax.random.PRNGKey(3)
+        kq = jax.random.PRNGKey(args.seed + 3)
         for i in range(args.quick_train):
             sc = streams[i % len(streams)]
             fr, bx, vl = generate_chunk(kq, sc, i * 4, 4)
@@ -81,12 +105,14 @@ def main(argv=None):
     env = MultiStreamEnv(env_cfg)
     if args.controller == "sac":
         controller = BandwidthController.create(
-            jax.random.PRNGKey(2), high_state_dim(env_cfg), args.streams)
+            jax.random.PRNGKey(args.seed + 2), high_state_dim(env_cfg),
+            args.streams)
 
-    key = jax.random.PRNGKey(0)
-    f1_all, lat_all = [], []
+    key = jax.random.PRNGKey(args.seed)
+    f1_all, lat_all, chunk_wall = [], [], []
     t_start = time.time()
     for t in range(args.chunks):
+        t_chunk = time.time()
         env.t = t
         if controller is not None:
             props = controller.proportions(key, env.observe_high(), t,
@@ -102,25 +128,31 @@ def main(argv=None):
             b, s, types = runtime.process_chunk(c, t, packet)
             lat = runtime.compute_latency(types, packet.total_bits, alloc[c],
                                           stream=c)
-            nms = jax.jit(lambda bb, ss: D.greedy_nms(bb, ss,
-                                                      iou_thresh=0.4,
-                                                      top_k=16))
-            f1 = np.mean([float(D.f1_score(
-                *nms(jax.numpy.asarray(b[i]), jax.numpy.asarray(s[i])),
-                jax.numpy.asarray(boxes[i]), jax.numpy.asarray(valid[i])))
-                for i in range(frames.shape[0])])
+            f1 = float(np.mean(np.asarray(_chunk_f1(b, s, boxes, valid))))
             f1_all.append(f1)
             lat_all.append(lat["total"])
             print(f"chunk {t} stream {c}: bw={alloc[c]:7.0f}kbps "
                   f"types={types.tolist()} f1={f1:.3f} "
                   f"lat={lat['total'] * 1e3:6.1f}ms")
+            if on_chunk is not None:
+                on_chunk(dict(t=t, stream=c, packet=packet, gt_boxes=boxes,
+                              gt_valid=valid, bw_kbps=float(alloc[c]),
+                              boxes=b, scores=s, types=types,
+                              params=params, det_cfg=det_cfg))
+        chunk_wall.append(time.time() - t_chunk)
     wall = time.time() - t_start
+    runtime.close()
     fps = args.streams * args.chunks * args.chunk_frames / wall
     print(f"\nmean F1 {np.mean(f1_all):.3f} | mean latency "
           f"{np.mean(lat_all) * 1e3:.1f} ms | deferred chunks "
           f"{runtime.deferred} | wall {wall:.1f}s ({fps:.1f} fps incl. "
           f"encode sim)")
-
+    return {"mean_f1": float(np.mean(f1_all)),
+            "mean_latency_s": float(np.mean(lat_all)),
+            "deferred": int(runtime.deferred), "wall_s": wall, "fps": fps,
+            "chunk_wall_s": chunk_wall,
+            "stream_stats": {s: st.as_dict()
+                             for s, st in sorted(runtime.stats.items())}}
 
 if __name__ == "__main__":
     main()

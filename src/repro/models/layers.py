@@ -371,7 +371,6 @@ def moe_block(x, w_router, w1, w3, w2, moe: MoEConfig):
     reduced with one psum over the tensor axis after token combine.
     """
     from jax.sharding import PartitionSpec as P
-    from repro.distributed.shard_map_compat import shard_map_compat
     from repro.distributed.context import current_ctx
 
     B, S, d = x.shape
@@ -430,10 +429,10 @@ def moe_block(x, w_router, w1, w3, w2, moe: MoEConfig):
         aux = lax.pmean(aux, batch)
         return out.reshape(Bl, S, d), aux
 
-    out, aux = shard_map_compat(
+    out, aux = jax.shard_map(
         body, mesh=ctx.mesh,
         in_specs=(P(batch), P(), P(None, None, tensor), P(None, None, tensor),
                   P(None, tensor, None)),
-        out_specs=(P(batch), P()),
+        out_specs=(P(batch), P()), check_vma=False,
     )(x, w_router, w1, w3, w2)
     return out, aux

@@ -63,9 +63,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.hybrid_encoder import HybridPacket
-from repro.core.hybrid_decoder import (PipelineCosts, _upscale_mvs,
+from repro.core.hybrid_decoder import (PipelineCosts, execution_planes,
                                        pipeline_cost)
-from repro.codec.rate_model import QUALITY_LADDER, upscale_nearest
+from repro.codec.rate_model import QUALITY_LADDER
 from repro.core.reuse import reuse_chunk
 from repro.models import detection as D
 from repro.serving.elastic import ElasticPool
@@ -81,11 +81,12 @@ f32 = np.float32
 # module-level jits — one trace per batch signature, shared by every runtime
 # ---------------------------------------------------------------------------
 @partial(jax.jit, static_argnames=("hd_hw", "roi", "anchor_search"))
-def _stage_chunk(types, anchor_hd, recon, mv, residual_q, *, hd_hw,
+def _stage_chunk(types, anchor_hd, recon, mv, residual_q, qtab, *, hd_hw,
                  roi=None, anchor_search=False):
-    """Stage one chunk on device: upscale the LR video to analytics
-    resolution, select each frame's execution plane (decoded HD anchor for
-    type-1, upscaled LR for the rest), and upscale the motion vectors —
+    """Stage one chunk on device: build each frame's execution plane
+    (decoded HD anchor for type-1, the upscaled LR frame quality-transferred
+    from its anchor for type-2 — ``hybrid_decoder.execution_planes``, the
+    same planes the fused decode runs) and upscale the motion vectors —
     one async dispatch, nothing touches the host.  With ``roi`` set (a
     static ``repro.core.roi.RoiConfig``) the relevance head also scores
     each HD region from the codec's macroblock statistics; the (T, R)
@@ -94,10 +95,8 @@ def _stage_chunk(types, anchor_hd, recon, mv, residual_q, *, hd_hw,
     per-rung anchor bit planes (``ladder_bits`` over the HD anchor plane,
     (T, Q)) ride along too — staged in this same async dispatch so a
     downstream in-trace budget pick costs no extra host round trip."""
-    H, W = hd_hw
-    lr_up = upscale_nearest(recon, H, W)
-    frames = jnp.where((types == 1)[:, None, None], anchor_hd, lr_up)
-    mvs = _upscale_mvs(mv, (H, W))
+    frames, mvs = execution_planes(recon, mv, residual_q, qtab, types,
+                                   anchor_hd)
     rung_bits = None
     if anchor_search:
         from repro.codec.image_codec import ladder_bits
@@ -644,7 +643,8 @@ class EdgeRuntime:
         frames_dev, mvs_dev, rscores_dev, rung_bits_dev = _stage_chunk(
             jnp.asarray(types), jnp.asarray(packet.anchor_hd),
             jnp.asarray(enc.recon), jnp.asarray(enc.mv),
-            jnp.asarray(enc.residual_q), hd_hw=(H, W), roi=self.roi,
+            jnp.asarray(enc.residual_q), jnp.asarray(enc.qtab),
+            hd_hw=(H, W), roi=self.roi,
             anchor_search=self.anchor_search)
 
         n_cells = (H // self.det_cfg.stride) * (W // self.det_cfg.stride)

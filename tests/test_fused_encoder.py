@@ -256,8 +256,8 @@ def test_block_sad_rejects_unknown_search():
        bf16=st.booleans())
 def test_motion_sad_kernel_bit_exact_vs_scan_property(nby, nbx, radius,
                                                       seed, bf16):
-    """The retiled kernel (multi-row grid steps, fast two-stage selection
-    reduce + oracle-order winner recompute) reproduces ``block_sad_scan``
+    """The kernel (multi-row grid steps, rotation-based selection +
+    oracle-order winner recompute) reproduces ``block_sad_scan``
     bit-for-bit — MVs including tie-breaks AND SADs — on integer-valued
     (real-video-domain) frames, where every f32 summation order is exact.
     bf16 storage is lossless for 0..255 integers, so even the bf16 kernel

@@ -254,6 +254,30 @@ def test_runtime_one_padded_dispatch_per_chunk():
         assert calls[0][0] % cfg.batch_size == 0  # padded, fixed shape set
 
 
+def test_runtime_pipelines_match_decode_execute_chunk():
+    """The runtime serves the reference decode's pipelines: a chunk with
+    anchor, quality-transfer and reuse frames comes back with the
+    packet's types and the detections of ``decode_execute_chunk`` —
+    pipeline-② rows are quality-transferred from their anchor, not the
+    plain upscaled LR frame."""
+    from repro.core.hybrid_decoder import decode_execute_chunk
+    from repro.serving.runtime import EdgeRuntime
+    from repro.serving.scheduler import ServingConfig
+    packet, params, det_cfg, gtb, gtv = _setup_chunk(T=6)
+    pk = dataclasses.replace(
+        packet, types=np.asarray([1, 2, 2, 3, 2, 3], packet.types.dtype))
+    rt = EdgeRuntime(ServingConfig(n_streams=1), params, det_cfg)
+    boxes, scores, types = rt.process_chunk(0, 0, pk)
+    ref = decode_execute_chunk(
+        pk.video, jnp.asarray(pk.types), jnp.asarray(pk.anchor_hd), gtb,
+        gtv, params, det_cfg, bw_kbps=8000.0, total_bits=pk.total_bits)
+    np.testing.assert_array_equal(types, pk.types)
+    # the detector sees the two routes' frames in different batches, and
+    # XLA:CPU conv numerics depend on the batch size
+    np.testing.assert_allclose(boxes, np.asarray(ref["boxes"]), atol=1e-3)
+    np.testing.assert_allclose(scores, np.asarray(ref["scores"]), atol=1e-5)
+
+
 def test_runtime_deep_overload_falls_back_to_full_reuse():
     """When even anchors-only would blow the latency budget and a carry
     exists, the whole chunk runs on pipeline ③ through the REAL admission

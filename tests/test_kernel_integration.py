@@ -44,9 +44,8 @@ def test_swa_pallas_matches_xla():
 
 
 def test_quality_transfer_kernel_path_in_core():
-    """Kernel and jnp paths agree on interior blocks (they differ only in
-    border policy: the kernel clamps horizontal offsets, warp_blocks
-    edge-pads — both valid codec conventions)."""
+    """Kernel and jnp paths agree on the whole frame: both edge-pad the
+    anchor, so border blocks that reach past the frame match too."""
     from repro.core.quality_transfer import transfer_frame
     H, W = 64, 96
     ks = jax.random.split(KEY, 3)
@@ -55,5 +54,4 @@ def test_quality_transfer_kernel_path_in_core():
     resid = jax.random.normal(ks[2], (H, W), jnp.float32) * 4
     a = transfer_frame(anchor, mv, resid, use_kernel=False)
     b = transfer_frame(anchor, mv, resid, use_kernel=True)
-    np.testing.assert_allclose(np.asarray(a)[16:-16, 16:-16],
-                               np.asarray(b)[16:-16, 16:-16], atol=1e-3)
+    np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-3)
