@@ -1,0 +1,8 @@
+"""Share of the traced window in which no operation ran on the device."""
+
+
+def read(ctx):
+    if ctx.trace is None:
+        return None
+    w = ctx.trace.window_s()
+    return 100.0 * (1.0 - ctx.trace.busy_s() / w) if w > 0 else None
