@@ -51,16 +51,25 @@ The accounting invariant every chaos test asserts —
 ``frames_in == frames_inferred + frames_reused + frames_skipped`` — is
 established at SUBMIT time (types are decided by host control), so it
 holds for every stream even while its chunk is still in flight.
+
+Instrumentation (docs/serving_async.md, "Tracing and counters"): host
+spans ``edge.*`` (``jax.profiler.TraceAnnotation``, on the profiler's
+clock, carrying ``stream``/``chunk``/``shard`` ids), a
+``jax.named_scope`` per serving program, always-on host counters
+(``EdgeRuntime.counters``) and a process-wide trace count per program
+(``TRACE_COUNTS``).  None of them reads a device value.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
-from collections import defaultdict, deque
+from collections import Counter, defaultdict, deque
 from functools import partial
 
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.core.hybrid_encoder import HybridPacket
 from repro.core.hybrid_decoder import (PipelineCosts, execution_planes,
@@ -80,6 +89,23 @@ f32 = np.float32
 # ---------------------------------------------------------------------------
 # module-level jits — one trace per batch signature, shared by every runtime
 # ---------------------------------------------------------------------------
+PROGRAMS = ("stage", "gather", "detect", "finish", "hold")
+
+# process-wide: how often JAX traced each serving program (its Python body
+# runs only while tracing), so a retrace names the program that caused it
+TRACE_COUNTS: Counter = Counter()
+
+
+@contextlib.contextmanager
+def _program(name: str):
+    """Body of a serving jit: count the trace and name the device ops
+    (``jax.named_scope`` is op metadata; the XLA module keeps the jit's
+    own name)."""
+    TRACE_COUNTS[name] += 1
+    with jax.named_scope(name):
+        yield
+
+
 @partial(jax.jit, static_argnames=("hd_hw", "roi", "anchor_search"))
 def _stage_chunk(types, anchor_hd, recon, mv, residual_q, qtab, *, hd_hw,
                  roi=None, anchor_search=False):
@@ -95,19 +121,20 @@ def _stage_chunk(types, anchor_hd, recon, mv, residual_q, qtab, *, hd_hw,
     per-rung anchor bit planes (``ladder_bits`` over the HD anchor plane,
     (T, Q)) ride along too — staged in this same async dispatch so a
     downstream in-trace budget pick costs no extra host round trip."""
-    frames, mvs = execution_planes(recon, mv, residual_q, qtab, types,
-                                   anchor_hd)
-    rung_bits = None
-    if anchor_search:
-        from repro.codec.image_codec import ladder_bits
-        rung_bits = jax.vmap(ladder_bits)(anchor_hd)
-    if roi is None:
-        return frames, mvs, None, rung_bits
-    from repro.core.roi import region_grid, region_scores
-    nry, nrx = region_grid(hd_hw, roi)
-    scores = region_scores(mv, residual_q, recon.shape[1:], hd_hw, roi)
-    return (frames, mvs, scores.reshape(types.shape[0], nry * nrx),
-            rung_bits)
+    with _program("stage"):
+        frames, mvs = execution_planes(recon, mv, residual_q, qtab, types,
+                                       anchor_hd)
+        rung_bits = None
+        if anchor_search:
+            from repro.codec.image_codec import ladder_bits
+            rung_bits = jax.vmap(ladder_bits)(anchor_hd)
+        if roi is None:
+            return frames, mvs, None, rung_bits
+        from repro.core.roi import region_grid, region_scores
+        nry, nrx = region_grid(hd_hw, roi)
+        scores = region_scores(mv, residual_q, recon.shape[1:], hd_hw, roi)
+        return (frames, mvs, scores.reshape(types.shape[0], nry * nrx),
+                rung_bits)
 
 
 @jax.jit
@@ -119,10 +146,12 @@ def _gather_batch(frames_seq, flat_idx, valid):
     ``flat_idx``: (n_pad,) row ``slot * T + frame_idx`` per batch entry;
     ``valid``: (n_pad,) mask — padding rows come out exactly zero, matching
     the legacy ``np.zeros_like`` padding semantics bit-for-bit."""
-    stacked = jnp.stack(frames_seq)
-    flat = stacked.reshape((-1,) + stacked.shape[2:])
-    batch = jnp.take(flat, jnp.clip(flat_idx, 0, flat.shape[0] - 1), axis=0)
-    return jnp.where(valid[:, None, None], batch, 0.0)
+    with _program("gather"):
+        stacked = jnp.stack(frames_seq)
+        flat = stacked.reshape((-1,) + stacked.shape[2:])
+        batch = jnp.take(flat, jnp.clip(flat_idx, 0, flat.shape[0] - 1),
+                         axis=0)
+        return jnp.where(valid[:, None, None], batch, 0.0)
 
 
 @jax.jit
@@ -131,10 +160,12 @@ def _gather_rows(rows_seq, flat_idx, valid):
     (T, R) region-score rows into the batch order.  Padding rows score 0
     everywhere — their gated patches run on the zero frames
     ``_gather_batch`` produced and are dropped at scatter time."""
-    stacked = jnp.stack(rows_seq)
-    flat = stacked.reshape((-1,) + stacked.shape[2:])
-    rows = jnp.take(flat, jnp.clip(flat_idx, 0, flat.shape[0] - 1), axis=0)
-    return jnp.where(valid[:, None], rows, 0.0)
+    with _program("gather"):
+        stacked = jnp.stack(rows_seq)
+        flat = stacked.reshape((-1,) + stacked.shape[2:])
+        rows = jnp.take(flat, jnp.clip(flat_idx, 0, flat.shape[0] - 1),
+                        axis=0)
+        return jnp.where(valid[:, None], rows, 0.0)
 
 
 @partial(jax.jit, static_argnames=("has_init",))
@@ -143,23 +174,25 @@ def _finish_chunk(types, pos, mvs, batch_boxes, batch_scores,
     """Scatter one ticket's rows out of the batched detector output and
     run pipeline-③ reuse — fused, so the carry slice (``boxes[-1]``)
     never leaves the device between chunks."""
-    mask = pos >= 0
-    idx = jnp.clip(pos, 0, batch_boxes.shape[0] - 1)
-    boxes_t = jnp.where(mask[:, None, None], batch_boxes[idx], 0.0)
-    scores_t = jnp.where(mask[:, None], batch_scores[idx], 0.0)
-    boxes, scores = reuse_chunk(
-        types, mvs, boxes_t, scores_t,
-        init_boxes=init_b if has_init else None,
-        init_scores=init_s if has_init else None)
-    return boxes, scores, boxes[-1], scores[-1]
+    with _program("finish"):
+        mask = pos >= 0
+        idx = jnp.clip(pos, 0, batch_boxes.shape[0] - 1)
+        boxes_t = jnp.where(mask[:, None, None], batch_boxes[idx], 0.0)
+        scores_t = jnp.where(mask[:, None], batch_scores[idx], 0.0)
+        boxes, scores = reuse_chunk(
+            types, mvs, boxes_t, scores_t,
+            init_boxes=init_b if has_init else None,
+            init_scores=init_s if has_init else None)
+        return boxes, scores, boxes[-1], scores[-1]
 
 
 @partial(jax.jit, static_argnames=("T",))
 def _hold_chunk(last_b, last_s, *, T):
     """Zero-motion pipeline-③ hold for an undeliverable chunk with a
     carry: the previous detections repeated across the chunk."""
-    return (jnp.broadcast_to(last_b[None], (T,) + last_b.shape),
-            jnp.broadcast_to(last_s[None], (T,) + last_s.shape))
+    with _program("hold"):
+        return (jnp.broadcast_to(last_b[None], (T,) + last_b.shape),
+                jnp.broadcast_to(last_s[None], (T,) + last_s.shape))
 
 
 def _pad_bucket(n: int, base: int) -> int:
@@ -269,6 +302,30 @@ class StreamStats:
         return {k: v for k, v in d.items() if not k.startswith("_")}
 
 
+@dataclasses.dataclass
+class DispatchCounters:
+    """What the runtime handed the device, as plain host ints, always on.
+    Each is taken from a host value already at hand (a shape, a count,
+    the ``nbytes`` of a host array), never from a device array.
+
+    ``dispatches``: calls of each serving program (``PROGRAMS``);
+    ``detector_rows``: padded detector rows as dispatched (the useful
+    ones are ``StreamStats.frames_inferred``); ``h2d_bytes``: packet
+    arrays copied host -> device at submit; ``d2h_bytes``: results
+    copied device -> host; ``inflight_waits``: batches retired by a
+    blocking wait under ``max_inflight``."""
+    dispatches: dict = dataclasses.field(
+        default_factory=lambda: dict.fromkeys(PROGRAMS, 0))
+    detector_rows: int = 0
+    h2d_bytes: int = 0
+    d2h_bytes: int = 0
+    inflight_waits: int = 0
+
+    def as_dict(self) -> dict:
+        """A snapshot (copies ``dispatches``)."""
+        return dataclasses.asdict(self)
+
+
 class EdgeRuntime:
     def __init__(self, cfg: ServingConfig, detector_params, det_cfg,
                  costs: PipelineCosts = PipelineCosts(), *,
@@ -307,12 +364,19 @@ class EdgeRuntime:
         # each row runs only its top-K gated region patches.
         roi = getattr(cfg, "roi", None)
         if roi is None:
-            infer_jit = jax.jit(lambda p, frames: D.decode_boxes(
-                D.forward(p, det_cfg, frames), det_cfg))
+            def detect(p, frames):
+                with _program("detect"):
+                    return D.decode_boxes(D.forward(p, det_cfg, frames),
+                                          det_cfg)
         else:
             from repro.core.roi import roi_infer
-            infer_jit = jax.jit(lambda p, payload: roi_infer(
-                p, det_cfg, roi, payload[0], payload[1]))
+
+            def detect(p, payload):
+                with _program("detect"):
+                    return roi_infer(p, det_cfg, roi, payload[0], payload[1])
+        # jitted as a lambda: the XLA module keeps the name jit__lambda,
+        # which the benchmark's module table (bench/modules.json) matches
+        infer_jit = jax.jit(lambda p, x: detect(p, x))
         self.roi = roi
         self.anchor_search = bool(getattr(cfg, "anchor_search", False))
 
@@ -343,6 +407,7 @@ class EdgeRuntime:
         # overload, and whole chunks forced onto reuse (deep overload)
         self.demoted_frames = np.zeros(self.n_shards, np.int64)
         self.reuse_fallback_chunks = np.zeros(self.n_shards, np.int64)
+        self.counters = DispatchCounters()
 
         # ------------------------------------------ async dispatch plane
         self.max_inflight = max(int(getattr(cfg, "max_inflight", 2)), 1)
@@ -400,9 +465,11 @@ class EdgeRuntime:
         slowdown) feeds the straggler detector, and the call hedges
         across active shards when the primary would blow the
         latency-quantile deadline."""
+        n_rows = frames[0].shape[0] if isinstance(frames, tuple) \
+            else frames.shape[0]
+        self.counters.dispatches["detect"] += 1
+        self.counters.detector_rows += int(n_rows)
         if shard is not None and self.faults is not None:
-            n_rows = frames[0].shape[0] if isinstance(frames, tuple) \
-                else frames.shape[0]
             base = n_rows / max(self.cfg.shard_capacity_fps, 1e-6)
             slow = self.faults.shard_slowdown(shard, self._t)
             self.straggler.record(shard, base * slow)
@@ -430,7 +497,9 @@ class EdgeRuntime:
                 "region scores are staged per ticket; use "
                 "submit_chunk/flush/poll (process_chunk)")
         boxes, scores = self._infer_batch_dev(jnp.asarray(frames), shard)
-        return list(zip(np.asarray(boxes), np.asarray(scores)))
+        out = np.asarray(boxes), np.asarray(scores)
+        self.counters.d2h_bytes += out[0].nbytes + out[1].nbytes
+        return list(zip(*out))
 
     # ------------------------------------------------- degradation ladder
     def _stats(self, stream: int) -> StreamStats:
@@ -535,6 +604,7 @@ class EdgeRuntime:
             tk.types = np.full(T, 3, packet.types.dtype)
             tk._dev_out = _hold_chunk(prev.last_boxes, prev.last_scores,
                                       T=T)
+            self.counters.dispatches["hold"] += 1
             st.frames_reused += T
             st.reuse_fallback_chunks += 1
             st.last_delivered = T
@@ -584,6 +654,12 @@ class EdgeRuntime:
         Per-stream ordering: submitting a stream's next chunk while its
         previous ticket is still pending first flushes the pipeline, so
         the pipeline-③ carry chain stays ordered (on device)."""
+        with TraceAnnotation("edge.submit", stream=stream, chunk=t,
+                             shard=self.stream_shard(stream)):
+            return self._submit_chunk(stream, t, packet)
+
+    def _submit_chunk(self, stream: int, t: int,
+                      packet: HybridPacket) -> ChunkTicket:
         self._t = t
         prev_tk = self._open.get(stream)
         if prev_tk is not None and not prev_tk.done:
@@ -640,12 +716,18 @@ class EdgeRuntime:
         # one async dispatch stages the whole chunk on device; values stay
         # there until the poll boundary (anchor_search additionally stages
         # the per-rung bit planes in the SAME dispatch)
-        frames_dev, mvs_dev, rscores_dev, rung_bits_dev = _stage_chunk(
-            jnp.asarray(types), jnp.asarray(packet.anchor_hd),
-            jnp.asarray(enc.recon), jnp.asarray(enc.mv),
-            jnp.asarray(enc.residual_q), jnp.asarray(enc.qtab),
-            hd_hw=(H, W), roi=self.roi,
-            anchor_search=self.anchor_search)
+        host = (types, packet.anchor_hd, enc.recon, enc.mv, enc.residual_q,
+                enc.qtab)
+        nbytes = sum(a.nbytes for a in host if isinstance(a, np.ndarray))
+        with TraceAnnotation("edge.h2d", stream=stream, chunk=t,
+                             bytes=nbytes):
+            dev = [jnp.asarray(a) for a in host]
+        self.counters.h2d_bytes += nbytes
+        with TraceAnnotation("edge.stage", stream=stream, chunk=t):
+            frames_dev, mvs_dev, rscores_dev, rung_bits_dev = _stage_chunk(
+                *dev, hd_hw=(H, W), roi=self.roi,
+                anchor_search=self.anchor_search)
+        self.counters.dispatches["stage"] += 1
 
         n_cells = (H // self.det_cfg.stride) * (W // self.det_cfg.stride)
         tk = ChunkTicket(stream, t, shard, types, (H, W),
@@ -678,23 +760,36 @@ class EdgeRuntime:
         detector asynchronously under the double-buffer cap, and finish
         each ticket's scatter+reuse on device."""
         T = int(tickets[0].types.shape[0])
-        by_stream = {tk.stream: tk for tk in tickets}
-        slot = {id(tk): i for i, tk in enumerate(tickets)}
         reqs = [r for tk in tickets for r in tk.reqs if r.pipeline == 1] \
             + [r for tk in tickets for r in tk.reqs if r.pipeline == 2]
         self.queues.take(reqs)
 
-        bb = bs = None
-        if reqs:
-            n = len(reqs)
-            n_pad = _pad_bucket(n, self.cfg.batch_size)
-            flat_idx = np.zeros(n_pad, np.int32)
-            valid = np.zeros(n_pad, bool)
-            for j, r in enumerate(reqs):
-                flat_idx[j] = slot[id(by_stream[r.stream])] * T \
-                    + r.frame_idx
-                valid[j] = True
-            k_pad = _pad_bucket(len(tickets), 1)
+        n = len(reqs)
+        n_pad = _pad_bucket(n, self.cfg.batch_size) if n else 0
+        with TraceAnnotation("edge.dispatch", shard=shard, rows=n_pad,
+                             valid=n):
+            bb, bs = self._dispatch_detector(shard, tickets, reqs, T, n_pad)
+            for tk in tickets:
+                with TraceAnnotation("edge.finish", stream=tk.stream,
+                                     chunk=tk.chunk_t):
+                    self._finish_ticket(tk, reqs, T, bb, bs)
+
+    def _dispatch_detector(self, shard: int, tickets: list[ChunkTicket],
+                           reqs: list, T: int, n_pad: int):
+        """Gather the group's rows into one padded batch and dispatch the
+        detector under the ``max_inflight`` cap; (None, None) without
+        rows."""
+        if not reqs:
+            return None, None
+        by_stream = {tk.stream: tk for tk in tickets}
+        slot = {id(tk): i for i, tk in enumerate(tickets)}
+        flat_idx = np.zeros(n_pad, np.int32)
+        valid = np.zeros(n_pad, bool)
+        for j, r in enumerate(reqs):
+            flat_idx[j] = slot[id(by_stream[r.stream])] * T + r.frame_idx
+            valid[j] = True
+        k_pad = _pad_bucket(len(tickets), 1)
+        with TraceAnnotation("edge.gather"):
             planes = tuple(tk.frames_dev for tk in tickets) \
                 + (tickets[0].frames_dev,) * (k_pad - len(tickets))
             batch = _gather_batch(planes, jnp.asarray(flat_idx),
@@ -704,42 +799,51 @@ class EdgeRuntime:
                     + (tickets[0].rscores_dev,) * (k_pad - len(tickets))
                 batch = (batch, _gather_rows(rows, jnp.asarray(flat_idx),
                                              jnp.asarray(valid)))
-            q = self._inflight[shard]
-            while len(q) >= self.max_inflight:
-                jax.block_until_ready(q.popleft())
+        self.counters.dispatches["gather"] += 1
+        q = self._inflight[shard]
+        if len(q) >= self.max_inflight:
+            with TraceAnnotation("edge.inflight_wait"):
+                while len(q) >= self.max_inflight:
+                    jax.block_until_ready(q.popleft())
+                    self.counters.inflight_waits += 1
+        with TraceAnnotation("edge.detect"):
             bb, bs = self._infer_batch_dev(batch, shard=shard)
             if self._shard_infer is not None:
                 # finish on the staging device: the carry must live on ONE
                 # device regardless of which shard ran the batch
                 home = next(iter(tickets[0].mvs_dev.devices()))
                 bb, bs = jax.device_put((bb, bs), home)
-            q.append((bb, bs))
+        q.append((bb, bs))
+        return bb, bs
 
-        for tk in tickets:
-            pos = np.full(T, -1, np.int32)
-            for j, r in enumerate(reqs):
-                if r.stream == tk.stream:
-                    pos[r.frame_idx] = j
-            if bb is None:
-                dbb = jnp.zeros((1, tk.n_cells, 4), jnp.float32)
-                dbs = jnp.zeros((1, tk.n_cells), jnp.float32)
-            else:
-                dbb, dbs = bb, bs
-            has_init = tk.init_b is not None
-            zb = jnp.zeros((tk.n_cells, 4), jnp.float32)
-            zs = jnp.zeros((tk.n_cells,), jnp.float32)
-            boxes, scores, last_b, last_s = _finish_chunk(
-                jnp.asarray(tk.types), jnp.asarray(pos), tk.mvs_dev,
-                dbb, dbs,
-                tk.init_b if has_init else zb,
-                tk.init_s if has_init else zs, has_init=has_init)
-            self.streams[tk.stream] = StreamState(last_b, last_s)
-            tk._dev_out = (boxes, scores)
-            tk.done = True
-            tk.frames_dev = tk.mvs_dev = tk.init_b = tk.init_s = None
-            tk.rscores_dev = None
-            if self._open.get(tk.stream) is tk:
-                del self._open[tk.stream]
+    def _finish_ticket(self, tk: ChunkTicket, reqs: list, T: int, bb, bs):
+        """Scatter the ticket's rows out of the batch and run pipeline-③
+        reuse on device (``_finish_chunk``); the ticket is then done."""
+        pos = np.full(T, -1, np.int32)
+        for j, r in enumerate(reqs):
+            if r.stream == tk.stream:
+                pos[r.frame_idx] = j
+        if bb is None:
+            dbb = jnp.zeros((1, tk.n_cells, 4), jnp.float32)
+            dbs = jnp.zeros((1, tk.n_cells), jnp.float32)
+        else:
+            dbb, dbs = bb, bs
+        has_init = tk.init_b is not None
+        zb = jnp.zeros((tk.n_cells, 4), jnp.float32)
+        zs = jnp.zeros((tk.n_cells,), jnp.float32)
+        boxes, scores, last_b, last_s = _finish_chunk(
+            jnp.asarray(tk.types), jnp.asarray(pos), tk.mvs_dev,
+            dbb, dbs,
+            tk.init_b if has_init else zb,
+            tk.init_s if has_init else zs, has_init=has_init)
+        self.counters.dispatches["finish"] += 1
+        self.streams[tk.stream] = StreamState(last_b, last_s)
+        tk._dev_out = (boxes, scores)
+        tk.done = True
+        tk.frames_dev = tk.mvs_dev = tk.init_b = tk.init_s = None
+        tk.rscores_dev = None
+        if self._open.get(tk.stream) is tk:
+            del self._open[tk.stream]
 
     def flush(self, shard: int | None = None):
         """Dispatch every pending ticket (optionally one shard's) —
@@ -748,26 +852,37 @@ class EdgeRuntime:
         still computing on device."""
         todo = [tk for tk in self._pending
                 if not tk.done and (shard is None or tk.shard == shard)]
-        groups: dict[tuple, list[ChunkTicket]] = {}
-        for tk in todo:
-            key = (tk.shard, int(tk.types.shape[0]), *tk.hw)
-            groups.setdefault(key, []).append(tk)
-        for key in sorted(groups):
-            self._dispatch_group(key[0], groups[key])
-        self._pending = [tk for tk in self._pending if not tk.done]
+        with TraceAnnotation("edge.flush", tickets=len(todo)):
+            groups: dict[tuple, list[ChunkTicket]] = {}
+            for tk in todo:
+                key = (tk.shard, int(tk.types.shape[0]), *tk.hw)
+                groups.setdefault(key, []).append(tk)
+            for key in sorted(groups):
+                self._dispatch_group(key[0], groups[key])
+            self._pending = [tk for tk in self._pending if not tk.done]
 
     def poll(self, ticket: ChunkTicket):
         """Block until the ticket's chunk is finished and return per-frame
         ``(boxes, scores, types)`` as host arrays — the ONE device->host
         transfer on the chunk path."""
-        if ticket._host is None:
-            if not ticket.done:
-                self.flush()
-            boxes, scores = ticket._dev_out
-            ticket._host = (np.asarray(boxes), np.asarray(scores),
-                            ticket.types)
-            ticket._dev_out = None
-        return ticket._host
+        with TraceAnnotation("edge.poll", stream=ticket.stream,
+                             chunk=ticket.chunk_t):
+            if ticket._host is None:
+                if not ticket.done:
+                    self.flush()
+                boxes, scores = ticket._dev_out
+                # the wait, apart from the copy (np.asarray would block)
+                with TraceAnnotation("edge.poll_wait", stream=ticket.stream,
+                                     chunk=ticket.chunk_t):
+                    jax.block_until_ready(ticket._dev_out)
+                nbytes = boxes.nbytes + scores.nbytes
+                with TraceAnnotation("edge.d2h", stream=ticket.stream,
+                                     chunk=ticket.chunk_t, bytes=nbytes):
+                    ticket._host = (np.asarray(boxes), np.asarray(scores),
+                                    ticket.types)
+                self.counters.d2h_bytes += nbytes
+                ticket._dev_out = None
+            return ticket._host
 
     def poll_all(self, tickets):
         """Flush once, then materialize every ticket."""
