@@ -35,8 +35,10 @@ diamond kernel (bit-exact MVs vs the fallback).
 bf16, SADs accumulated in f32) on both the fallbacks and the kernels.
 
 The warp (motion compensation) is the same block-gather primitive the
-hybrid decoder's quality transfer uses; its Pallas TPU kernel lives in
-``repro.kernels.qtransfer``.
+hybrid decoder's quality transfer uses; on a TPU its sub-tile shift is
+the Pallas kernel ``repro.kernels.tile_shift``.  The fused warp +
+residual kernel ``repro.kernels.qtransfer`` clips the motion to ±16, so
+it equals ``warp_blocks`` only within that bound.
 """
 from __future__ import annotations
 
@@ -235,28 +237,87 @@ def block_sad(cur, ref, radius: int = 8, *, use_kernel: bool = False,
 def warp_blocks(ref, mv):
     """Motion compensation: gather 16×16 blocks of ``ref`` at MV offsets.
 
-    ref: (H, W); mv: (nby, nbx, 2) int32 (dy, dx).  Pure-jnp oracle for the
-    qtransfer Pallas kernel.
+    ref: (H, W) with H, W multiples of 16; mv: (nby, nbx, 2) int32
+    (dy, dx), any magnitude.  The anchor is edge-padded by 16 px and each
+    output block copies the 16×16 block of the padded plane starting at
+    ``s = clip(block*16 + 16 + d, 0, padded - 16)`` on each axis: a block
+    moved past the padding reads the edge it moved past, never the
+    opposite one.  Float32 out.
+
+    No loop over blocks: the padded plane is laid out as rows of whole
+    aligned 16×16 tiles (``(tiles, 256)``), ``s = 16*q + f`` picks the
+    four tiles ``(q_y | q_y+1) × (q_x | q_x+1)`` the block straddles (one
+    row gather each), and the sub-tile offset ``f`` cuts the block out of
+    the square they make (``_sub_tile_shift``); every array stays
+    lane-dense ``(blocks, 256)``.  The fused warp + residual kernel
+    (``repro.kernels.qtransfer``) clips the motion to ±16 first, so it
+    equals this function only where |mv| ≤ 16.
     """
     H, W = ref.shape
     nby, nbx = mv.shape[:2]
-    # static padding: the worst-case offset bound is not static under jit,
-    # so pad by the fixed maximum supported radius.
-    R = 16
-    refp = jnp.pad(ref.astype(f32), R, mode="edge")
+    pad = MB
+    Hp, Wp = H + 2 * pad, W + 2 * pad
+    ty, tx = Hp // MB, Wp // MB
+    refp = jnp.pad(ref.astype(f32), pad, mode="edge")
+    tiles = refp.reshape(ty, MB, tx, MB).transpose(0, 2, 1, 3) \
+        .reshape(ty * tx, MB * MB)
 
-    by = jnp.arange(nby) * MB
-    bx = jnp.arange(nbx) * MB
+    sy = jnp.clip(jnp.arange(nby)[:, None] * MB + pad + mv[..., 0],
+                  0, Hp - MB).reshape(-1)
+    sx = jnp.clip(jnp.arange(nbx)[None, :] * MB + pad + mv[..., 1],
+                  0, Wp - MB).reshape(-1)
+    qy, fy = sy // MB, sy % MB
+    qx, fx = sx // MB, sx % MB
+    # the second tile is never read where f == 0, so clamping it is safe
+    qy1, qx1 = jnp.minimum(qy + 1, ty - 1), jnp.minimum(qx + 1, tx - 1)
 
-    def gather_block(y0, x0, d):
-        return lax.dynamic_slice(refp, (y0 + R + d[0], x0 + R + d[1]),
-                                 (MB, MB))
+    def tile(a, b):
+        return jnp.take(tiles, a * tx + b, axis=0, mode="clip")
 
-    rows = jax.vmap(
-        lambda y0, mvr: jax.vmap(
-            lambda x0, d: gather_block(y0, x0, d))(bx, mvr)
-    )(by, mv)                                     # (nby, nbx, MB, MB)
-    return rows.transpose(0, 2, 1, 3).reshape(H, W)
+    blocks = _sub_tile_shift(tile(qy, qx), tile(qy, qx1), tile(qy1, qx),
+                             tile(qy1, qx1), fy, fx)       # (blocks, 256)
+    return blocks.reshape(nby, nbx, MB, MB).transpose(0, 2, 1, 3) \
+        .reshape(H, W)
+
+
+def _sub_tile_shift(tl, tr, bl, br, fy, fx):
+    """The 16×16 block at (fy, fx) of each 32×32 square of four tiles.
+    On a TPU the Pallas lane-gather kernel (``repro.kernels.tile_shift``);
+    elsewhere the same selects in plain jnp.  Both move values unchanged,
+    so they agree bit for bit."""
+    from repro.kernels.motion_sad.ops import on_tpu
+    if on_tpu():
+        from repro.kernels.tile_shift import tile_shift
+        return tile_shift(tl, tr, bl, br, fy, fx)
+    return _shift_cols(_shift_rows(tl, bl, fy), _shift_rows(tr, br, fy), fx)
+
+
+def _shift_rows(top, bottom, f):
+    """Rows ``f .. f+15`` of each tile pair stacked top over bottom:
+    (N, 256) row-major tiles, ``f`` (N,) in [0, 16)."""
+    both = jnp.concatenate([top, bottom], axis=1)          # (N, 512)
+    out = top
+    for k in range(1, MB):
+        out = jnp.where(f[:, None] == k, both[:, k * MB:k * MB + MB * MB],
+                        out)
+    return out
+
+
+def _shift_cols(left, right, f):
+    """Columns ``f .. f+15`` of each tile pair set left beside right:
+    lane ``16r + c`` takes ``left[r, c+f]`` while ``c + f < 16``, else
+    ``right[r, c+f-16]``."""
+    n = left.shape[0]
+    col = jnp.arange(MB * MB) % MB
+    zero = jnp.zeros((n, MB), left.dtype)
+    lpad = jnp.concatenate([left, zero], axis=1)           # (N, 272)
+    rpad = jnp.concatenate([zero, right], axis=1)
+    out = left
+    for k in range(1, MB):
+        shifted = jnp.where(col + k < MB, lpad[:, k:k + MB * MB],
+                            rpad[:, k:k + MB * MB])
+        out = jnp.where(f[:, None] == k, shifted, out)
+    return out
 
 
 def accumulate_mv(mvs):

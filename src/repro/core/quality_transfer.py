@@ -30,8 +30,12 @@ def transfer_frame(anchor_hd, mv_acc, residual_px, blend: float = 1.0,
 
     anchor_hd: (H, W) the decoded HD anchor; mv_acc: (nby, nbx, 2)
     anchor-relative motion vectors; residual_px: (H, W) decoded residual.
+    The default route (``warp_blocks``) takes any motion: each block's
+    source start is clamped inside the anchor edge-padded by 16 px, so a
+    block moved past the padding reads the edge it moved past.
     ``use_kernel`` routes through the Pallas TPU kernel (interpret mode on
-    CPU); the pure-jnp path is the oracle.  Returns the enhanced frame.
+    CPU), which clips the motion to ±16 first: the two agree only where
+    |mv| <= 16.  Returns the enhanced frame.
     """
     if use_kernel:
         from repro.kernels.qtransfer.ops import qtransfer

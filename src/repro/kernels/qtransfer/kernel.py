@@ -10,11 +10,14 @@ selects (``repro.kernels.shift``): Mosaic has no gather and refuses a
 vector load at a dynamic unaligned offset.  The decoded residual band is
 added and the sum clipped to [0, 255].
 
-Semantics are those of ``repro.codec.motion.warp_blocks`` plus the
-residual: offsets are clipped to ±radius and blocks that reach past the
-frame read edge-replicated pixels.  At radius 16 with |mv| <= 16 — what
-``repro.core.quality_transfer.transfer_frame`` passes — the kernel and
-the jnp route agree exactly.
+Semantics: offsets are clipped to ±radius and blocks that reach past
+the frame read edge-replicated pixels.  At radius 16 with |mv| <= 16 the
+kernel and ``repro.codec.motion.warp_blocks`` plus the residual agree
+exactly.  Past ±16 they differ: ``warp_blocks`` keeps the motion and
+clamps the block's start inside the padded anchor, while the kernel
+clips the motion, so an interior block with |mv| > 16 reads another
+block.  ``repro.core.quality_transfer.transfer_frame(use_kernel=True)``
+clips to ±16 before the call.
 
 The MVs arrive expanded to one (dy, dx) pair per lane, so the offset of
 every lane is a plain vector operand.  The bf16 variant

@@ -67,6 +67,94 @@ def test_warp_blocks_identity():
                                np.asarray(f), atol=1e-4)
 
 
+def _warp_oracle(ref, mv):
+    """Plain loop: edge-pad the anchor by 16 px, clamp each block's start
+    inside the padded plane, copy the block."""
+    ref, mv = np.asarray(ref, np.float32), np.asarray(mv)
+    H, W = ref.shape
+    padded = np.pad(ref, 16, mode="edge")
+    out = np.empty_like(ref)
+    for by in range(H // 16):
+        for bx in range(W // 16):
+            y = min(max(by * 16 + 16 + int(mv[by, bx, 0]), 0), H + 16)
+            x = min(max(bx * 16 + 16 + int(mv[by, bx, 1]), 0), W + 16)
+            out[by * 16:by * 16 + 16, bx * 16:bx * 16 + 16] = \
+                padded[y:y + 16, x:x + 16]
+    return out
+
+
+def _motion(kind, nby, nbx, H, W, rng):
+    """(nby, nbx, 2) int32 motion of one class."""
+    shape = (nby, nbx, 2)
+    sign = rng.choice([-1, 1], shape)
+    if kind == "zero":
+        return np.zeros(shape, np.int32)
+    if kind == "within_pad":                       # |mv| 1..16
+        return (sign * rng.integers(1, 17, shape)).astype(np.int32)
+    if kind == "past_pad":                         # |mv| 17..64
+        return (sign * rng.integers(17, 65, shape)).astype(np.int32)
+    if kind == "beyond_frame":                     # |mv| past H or W
+        return (sign * rng.integers(max(H, W), 3 * max(H, W), shape)) \
+            .astype(np.int32)
+    # "edges": each edge block moves outward past the padding (its start
+    # goes negative, or past the far edge); interior blocks stay small
+    mv = rng.integers(-8, 9, shape).astype(np.int32)
+    out = rng.integers(17, 41, shape).astype(np.int32)
+    mv[0, :, 0], mv[-1, :, 0] = -out[0, :, 0], out[-1, :, 0]
+    mv[:, 0, 1], mv[:, -1, 1] = -out[:, 0, 1], out[:, -1, 1]
+    return mv
+
+
+@pytest.mark.parametrize("H,W", [(64, 96), (1088, 1920)])
+@pytest.mark.parametrize("kind", ["zero", "within_pad", "past_pad",
+                                  "beyond_frame", "edges"])
+def test_warp_blocks_matches_clamped_loop_oracle(H, W, kind):
+    """Every block copies the padded anchor at its start clamped inside
+    the padded plane, bit for bit: a block moved past the padding reads
+    the edge it moved past, never the opposite one."""
+    rng = np.random.default_rng([H, W, len(kind)])
+    ref = rng.uniform(0, 255, (H, W)).astype(np.float32)
+    mv = _motion(kind, H // 16, W // 16, H, W, rng)
+    got = np.asarray(jax.jit(warp_blocks)(jnp.asarray(ref), jnp.asarray(mv)))
+    assert got.dtype == np.float32
+    np.testing.assert_array_equal(got, _warp_oracle(ref, mv))
+
+
+def _warp_slice_form(ref, mv):
+    """The earlier per-block ``lax.dynamic_slice`` warp, kept as the
+    oracle where no block start leaves the padded plane."""
+    H, W = ref.shape
+    refp = jnp.pad(ref.astype(jnp.float32), 16, mode="edge")
+    by = jnp.arange(mv.shape[0]) * 16
+    bx = jnp.arange(mv.shape[1]) * 16
+
+    def block(y0, x0, d):
+        return jax.lax.dynamic_slice(refp, (y0 + 16 + d[0], x0 + 16 + d[1]),
+                                     (16, 16))
+
+    rows = jax.vmap(lambda y0, m: jax.vmap(
+        lambda x0, d: block(y0, x0, d))(bx, m))(by, mv)
+    return rows.transpose(0, 2, 1, 3).reshape(H, W)
+
+
+@pytest.mark.parametrize("H,W", [(64, 96), (352, 640)])
+def test_warp_blocks_encoder_motion_bit_identical_to_slice_form(H, W):
+    """The camera-side encoder warps at LR with |mv| <= search_radius (8):
+    the clamp never engages, so its prediction is the slice form's."""
+    radius = VideoCodecConfig().search_radius
+    frames, _, _ = generate_chunk(KEY, StreamConfig(height=H, width=W,
+                                                    n_objects=5), 0, 2)
+    searched, _ = block_sad(frames[1], frames[0], radius=radius)
+    rng = np.random.default_rng(H)
+    drawn = jnp.asarray(rng.integers(-radius, radius + 1,
+                                     searched.shape).astype(np.int32))
+    for mv in (searched, drawn):
+        assert int(jnp.abs(mv).max()) <= radius
+        np.testing.assert_array_equal(
+            np.asarray(jax.jit(warp_blocks)(frames[0], mv)),
+            np.asarray(jax.jit(_warp_slice_form)(frames[0], mv)))
+
+
 def test_video_codec_quality_and_bits_monotone():
     frames, _, _ = generate_chunk(KEY, StreamConfig(height=64, width=96),
                                   0, 3)

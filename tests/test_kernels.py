@@ -12,6 +12,7 @@ from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.flash_attention.ref import attention_ref
 from repro.kernels.qtransfer.ops import qtransfer
 from repro.kernels.qtransfer.ref import qtransfer_ref
+from repro.kernels.tile_shift import tile_shift
 
 
 # ---------------------------------------------------------------- flash
@@ -109,3 +110,21 @@ def test_blockdct_energy_decreases_with_quality():
         qq, _ = blockdct_quantize(blocks, q, interpret=True)
         nz.append(int((jnp.abs(qq) > 0).sum()))
     assert nz[0] <= nz[1] <= nz[2]
+
+
+# ---------------------------------------------------------- tile shift
+@pytest.mark.parametrize("n", [12, 24, 520])
+def test_tile_shift_matches_jnp_selects(n):
+    """The Pallas lane-gather kernel cuts the same 16x16 block out of each
+    32x32 square of four tiles as the plain-jnp selects, bit for bit,
+    for every (fy, fx) and a block count that fills no whole step."""
+    from repro.codec.motion import _shift_cols, _shift_rows
+    rng = np.random.default_rng(n)
+    tl, tr, bl, br = (jnp.asarray(rng.uniform(0, 255, (n, 256))
+                                  .astype(np.float32)) for _ in range(4))
+    f = np.arange(n) % 256                 # every (fy, fx) once n >= 256
+    fy, fx = jnp.asarray(f // 16, jnp.int32), jnp.asarray(f % 16, jnp.int32)
+    want = _shift_cols(_shift_rows(tl, bl, fy), _shift_rows(tr, br, fy), fx)
+    got = jax.jit(lambda *a: tile_shift(*a, interpret=True))(
+        tl, tr, bl, br, fy, fx)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

@@ -14,6 +14,7 @@ program compiled for a described chip can be written to it but not read
 back without one).
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -23,6 +24,7 @@ from repro.kernels.blockdct.kernel import blockdct_tiles
 from repro.kernels.motion_sad.kernel import motion_sad_rows
 from repro.kernels.qtransfer.kernel import qtransfer_rows
 from repro.kernels.roi_gather.kernel import roi_gather_patches
+from repro.kernels.tile_shift import tile_shift
 
 SIZES = [(1088, 1920), (720, 1280)]
 
@@ -95,3 +97,40 @@ def test_blockdct_compiles_for_v5e(one_chip, H, W):
         return blockdct_tiles(blocks, dmat, qtab, interpret=False)
     _compile_for_chip(fn, one_chip, ((H * W // 64, 8, 8), jnp.float32),
                       ((8, 8), jnp.float32), ((8, 8), jnp.float32))
+
+
+@pytest.mark.parametrize("H,W", SIZES)
+def test_tile_shift_compiles_for_v5e(one_chip, H, W):
+    n = (H // 16) * (W // 16)
+
+    def fn(tl, tr, bl, br, fy, fx):
+        return tile_shift(tl, tr, bl, br, fy, fx, interpret=False)
+    _compile_for_chip(fn, one_chip, *[((n, 256), jnp.float32)] * 4,
+                      ((n,), jnp.int32), ((n,), jnp.int32))
+
+
+def test_stage_chunk_has_no_per_block_loop_on_v5e(one_chip, monkeypatch):
+    """The staging jit at the 9 x 1080p cell's shapes (T = 30, LR
+    352x640), on the route the chip takes, lowers the quality-transfer
+    warp to whole-tile gathers and the Pallas shift: no ``while`` loop
+    carries an array with one entry per 16x16 block of the chunk (the
+    per-block slice form became one such loop, 244,800 trips a chunk)."""
+    from repro.kernels.motion_sad import ops
+    from repro.serving.runtime import _stage_chunk
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    H, W, T, h, w = 1088, 1920, 30, 352, 640
+    blocks = T * (H // 16) * (W // 16)
+
+    def s(shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    stage = _stage_chunk.__wrapped__          # traced afresh, on this route
+    text = jax.jit(lambda *a: stage(*a, hd_hw=(H, W))).lower(
+        s((T,), jnp.int32), s((T, H, W)), s((T, h, w)),
+        s((T, h // 16, w // 16, 2), jnp.int32),
+        s((T, (h // 8) * (w // 8), 8, 8)), s((8, 8))).compile().as_text()
+    assert "tpu_custom_call" in text
+    carried = [line.split(" while(")[0] for line in text.splitlines()
+               if " while(" in line]
+    per_block = [c for c in carried if re.search(rf"\[{blocks}[,\]]", c)]
+    assert not per_block, per_block
