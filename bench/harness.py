@@ -2,8 +2,9 @@
 check and the metrics.
 
 Everything a cell needs is found by name: the cell in ``BENCHMARK.json``,
-its configuration in ``configs/<name>.json``, its traffic mix in
-``traffic/<name>.json`` and each metric's reader in
+its configuration in ``configs/<name>.json``, the detector that
+configuration's ``detector.arch`` names in ``detectors/<arch>.py``, its
+traffic mix in ``traffic/<name>.json`` and each metric's reader in
 ``metrics/<name>.py``.  The program under test is the edge runtime
 (``repro.serving.runtime.EdgeRuntime``) driven through ``submit_chunk``,
 ``flush`` and ``poll``; the camera side (``encode_hybrid``) makes its
@@ -13,7 +14,6 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-import importlib.util
 import json
 import os
 import shutil
@@ -23,6 +23,8 @@ import time
 import types as pytypes
 
 import numpy as np
+
+from bench.loader import detector, reader
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
@@ -72,15 +74,6 @@ def metrics_of(bench: dict, kind: str, workload: str) -> list:
             if "workloads" not in m or workload in m["workloads"]]
 
 
-def reader(name: str):
-    path = os.path.join(HERE, "metrics", name + ".py")
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
-
-
 def peaks_for(kind: str) -> dict:
     table = _json(os.path.join(HERE, "peaks.json"))["devices"]
     if kind not in table:
@@ -90,29 +83,9 @@ def peaks_for(kind: str) -> dict:
 
 # ------------------------------------------------------------ set-up parts
 def make_weights(seed: int, det: dict):
-    """Detector weights on the device in one jitted call: fan-in normal
-    kernels and small normal biases, float32."""
-    import jax
-    import jax.numpy as jnp
-
+    """The detector's weights, made on the device from the seed."""
     from bench.video import WEIGHTS, seed_key
-
-    chans = tuple(det["channels"])
-
-    @jax.jit
-    def init(key):
-        ks = jax.random.split(key, 2 * len(chans) + 2)
-        p, cin = {}, 1
-        for i, c in enumerate(chans):
-            p[f"conv{i}"] = jax.random.normal(ks[2 * i], (3, 3, cin, c)) \
-                / jnp.sqrt(9.0 * cin)
-            p[f"bias{i}"] = 0.05 * jax.random.normal(ks[2 * i + 1], (c,))
-            cin = c
-        p["head"] = jax.random.normal(ks[-2], (1, 1, cin, 5)) / jnp.sqrt(cin)
-        p["head_b"] = 0.05 * jax.random.normal(ks[-1], (5,))
-        return p
-
-    return init(seed_key(seed, WEIGHTS))
+    return detector(det["arch"]).weights(seed_key(seed, WEIGHTS), det)
 
 
 def make_packets(seed: int, cfg: dict, traffic: dict) -> list:
@@ -157,20 +130,20 @@ def packet_arrays(pk) -> dict:
 
 
 def make_runtime(cfg: dict, params):
-    from repro.models.detection import TinyDetectorConfig
+    """The program's edge runtime for the configuration, with the
+    detector that ``detector.arch`` names."""
     from repro.serving.runtime import EdgeRuntime
     from repro.serving.scheduler import ServingConfig
 
     det = cfg["detector"]
-    dcfg = TinyDetectorConfig(channels=tuple(det["channels"]),
-                              stride=det["stride"], dtype=det["dtype"])
     s = cfg["serving"]
     scfg = ServingConfig(n_streams=cfg["cameras"],
                          batch_size=s["batch_size"],
                          max_inflight=s["max_inflight"],
                          gpu_capacity_fps=s["gpu_capacity_fps"],
                          latency_budget=cfg["latency_budget_s"])
-    return EdgeRuntime(scfg, params, dcfg)
+    return EdgeRuntime(scfg, params,
+                       detector(det["arch"]).program_config(det))
 
 
 # ------------------------------------------------------------- the window

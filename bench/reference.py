@@ -17,9 +17,8 @@ Per frame:
 * type 3: no inference; the previous frame's boxes move by minus the mean
   HD motion vector of the macroblocks each box covers.
 
-The detector is three 3x3 convolutions (stride 2, ReLU) and a 1x1 head,
-decoded per output cell into an objectness score and a (cy, cx, h, w)
-box.
+The detector is the module of ``bench/detectors/`` that the
+configuration's ``detector.arch`` names (its docstring describes it).
 
 ``dtype`` is the precision of every floating array: float32 (at the
 highest matmul precision) is the reference, bfloat16 is the control
@@ -29,12 +28,13 @@ is integer and the same in both.
 from __future__ import annotations
 
 import math
-from functools import partial
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+
+from bench.loader import detector
 
 MB = 16          # macroblock side of the codec
 WARP_PAD = 16    # edge padding of the anchor before the block gather
@@ -146,45 +146,6 @@ def detector_inputs(pk: dict, dt):
     return jnp.stack(frames), mv_hd
 
 
-@partial(jax.jit, static_argnames=("stride",))
-def detector_raw(params, frames, *, stride: int):
-    """(B, H, W) frames -> (B, H/stride, W/stride, 5) raw head output."""
-    dt = frames.dtype
-    x = (frames / 255.0 - 0.5).astype(dt)[..., None]
-    n_down = int(round(math.log2(stride)))
-    i = 0
-    while f"conv{i}" in params:
-        s = 2 if i < n_down else 1
-        x = lax.conv_general_dilated(
-            x, params[f"conv{i}"].astype(dt), (s, s), "SAME",
-            dimension_numbers=("NHWC", "HWIO", "NHWC"),
-            precision=_precision(dt))
-        x = jax.nn.relu(x + params[f"bias{i}"].astype(dt))
-        i += 1
-    return lax.conv_general_dilated(
-        x, params["head"].astype(dt), (1, 1), "SAME",
-        dimension_numbers=("NHWC", "HWIO", "NHWC"),
-        precision=_precision(dt)) + params["head_b"].astype(dt)
-
-
-@partial(jax.jit, static_argnames=("stride",))
-def decode(raw, *, stride: int):
-    """Raw head output -> (B, cells, 4) cxcywh boxes and (B, cells)
-    scores, in the raw output's dtype."""
-    dt = raw.dtype
-    B, hc, wc, _ = raw.shape
-    score = jax.nn.sigmoid(raw[..., 0])
-    cy = (jnp.arange(hc, dtype=dt)[None, :, None] + 0.5
-          + jnp.tanh(raw[..., 1])) * stride
-    cx = (jnp.arange(wc, dtype=dt)[None, None, :] + 0.5
-          + jnp.tanh(raw[..., 2])) * stride
-    bh = jnp.exp(jnp.clip(raw[..., 3], -3, 3)) * stride
-    bw = jnp.exp(jnp.clip(raw[..., 4], -3, 3)) * stride
-    boxes = jnp.stack([jnp.broadcast_to(cy, score.shape),
-                       jnp.broadcast_to(cx, score.shape), bh, bw], -1)
-    return boxes.reshape(B, -1, 4), score.reshape(B, -1)
-
-
 @jax.jit
 def shift(boxes, mv):
     """Move each box by minus the mean motion vector of the macroblocks
@@ -225,16 +186,14 @@ def reuse(types, boxes, scores, mv_hd):
 
 def chunk(pk: dict, params: dict, det: dict, dt):
     """What the edge should return for one packet: per-frame (boxes,
-    scores) as host arrays, in float32."""
-    stride = det["stride"]
+    scores) as host arrays, in float32, and the HD motion."""
     types = np.asarray(pk["types"])
     with jax.default_matmul_precision("highest" if dt == jnp.float32
                                       else "default"):
         frames, mv_hd = detector_inputs(pk, dt)
         inferred = np.nonzero(types != 3)[0]
-        p = {k: jnp.asarray(v, dt) for k, v in params.items()}
-        raw = detector_raw(p, frames[inferred], stride=stride)
-        b_inf, s_inf = decode(raw, stride=stride)
+        b_inf, s_inf = detector(det["arch"]).reference(
+            params, frames[inferred], det, dt)
         T, n_cells = frames.shape[0], b_inf.shape[1]
         boxes = jnp.zeros((T, n_cells, 4), dt).at[inferred].set(b_inf)
         scores = jnp.zeros((T, n_cells), dt).at[inferred].set(s_inf)

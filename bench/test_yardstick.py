@@ -16,11 +16,11 @@ from functools import partial
 import numpy as np
 import pytest
 
-from bench import compare, flops, harness, stats, trace
+from bench import compare, flops, harness, loader, stats, trace
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = harness.benchmark()
-DET = {"channels": [16, 32, 64], "stride": 8}
+DET = {"arch": "tiny", "channels": [16, 32, 64], "stride": 8}
 
 
 # ----------------------------------------------------------- trace reduction
@@ -117,6 +117,20 @@ def test_dispatch_cost_counts_the_padded_rows_as_they_run():
     # 4.5 inferred frames pad to an 8-row batch: the roofline counts 8
     assert flops.dispatch_cost(DET, (1088, 1920), 8)[0] == pytest.approx(
         8 * 2.577e9, rel=1e-3)
+
+
+def test_tiny_counts_and_the_dispatch_lookup():
+    tiny = loader.detector("tiny")
+    assert tiny.frame_flops(DET, 1088, 1920) == 2_577_776_640
+    # dispatch_cost looks the counts up by arch: the formula it had
+    # before the detector modules, for any row count
+    for rows in (8, 16, 32):
+        assert flops.dispatch_cost(DET, (1088, 1920), rows) == (
+            rows * 2 * (544 * 960 * 16 * 9 + 272 * 480 * 32 * 16 * 9
+                        + 136 * 240 * 64 * 32 * 9 + 136 * 240 * 64 * 5),
+            rows * 4 * (1088 * 1920 + 2 * (544 * 960 * 16 + 272 * 480 * 32
+                                           + 136 * 240 * 64)
+                        + 5 * 136 * 240))
 
 
 def test_frame_bytes_layer_by_layer():
@@ -299,6 +313,24 @@ def test_bfloat16_control_is_incorrect():
     r = control.control_run("full.paper_mix", 11, 0.3, require_chip=False,
                             peaks=PEAKS, overrides=_small(width=1152))
     assert not r["correct"], r["checks"]
+
+
+def test_a_detector_found_by_an_unseen_name_runs_end_to_end(tmp_path,
+                                                           monkeypatch):
+    """A new detector needs only new files: a copy of ``tiny`` under a
+    name no file of the benchmark mentions, with other channels, runs
+    through the harness and the reference."""
+    shutil.copy(os.path.join(ROOT, "bench", "detectors", "tiny.py"),
+                tmp_path / "probe_arch7.py")
+    monkeypatch.setattr(loader, "DETECTORS", str(tmp_path))
+    ov = _small()
+    ov["config"]["detector"] = {"arch": "probe_arch7",
+                                "channels": [8, 16, 24, 24], "stride": 8,
+                                "dtype": "float32"}
+    r = _run("full.dense_infer", overrides=ov)
+    assert r["correct"], r["checks"]
+    mod = loader.detector("probe_arch7")
+    assert mod.__file__ == str(tmp_path / "probe_arch7.py")
 
 
 @pytest.mark.parametrize("workload", [w["name"] for w in BENCH["workloads"]])

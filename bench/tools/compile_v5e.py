@@ -5,8 +5,9 @@
         --config biswift9_1080p
 
 Programs: the staging jit (``_stage_chunk``), the batch gather, the
-full-frame detector dispatch at 32 rows, and the finish jit.  A compile
-that passes is not a chip run.
+full-frame detector dispatch at ``--rows`` rows as the runtime builds it
+(with the detector that the configuration's ``detector.arch`` names),
+and the finish jit.  A compile that passes is not a chip run.
 """
 from __future__ import annotations
 
@@ -32,8 +33,10 @@ def main(argv=None):
     from repro.codec.rate_model import (ladder_for_bandwidth,
                                         ladder_lr_shape,
                                         video_bandwidth_share)
-    from repro.models import detection as D
     from repro.serving import runtime as R
+
+    from bench import harness
+    from bench.loader import detector
 
     with open(os.path.join(HERE, "configs", args.config + ".json")) as f:
         cfg = json.load(f)
@@ -46,16 +49,20 @@ def main(argv=None):
         cfg["uplink_kbps"] / cfg["cameras"]))
     h, w = ladder_lr_shape(level, H, W)
     det = cfg["detector"]
-    dcfg = D.TinyDetectorConfig(channels=tuple(det["channels"]),
-                                stride=det["stride"], dtype=det["dtype"])
+    arch = detector(det["arch"])
 
     def s(shape, dt=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one)
 
-    params = jax.tree.map(lambda x: s(x.shape, x.dtype),
-                          jax.eval_shape(lambda: D.init(
-                              jax.random.PRNGKey(0), dcfg)))
-    n_cells = (H // dcfg.stride) * (W // dcfg.stride)
+    params = jax.tree.map(lambda x: s(x.shape, x.dtype), jax.eval_shape(
+        lambda k: arch.weights(k, det), jax.random.PRNGKey(0)))
+
+    def detect(p, x):
+        # the runtime built on the traced weights: its own dispatch
+        return harness.make_runtime(cfg, p)._infer(x)
+
+    dispatch = jax.jit(detect).lower(params, s((args.rows, H, W)))
+    n_cells = dispatch.out_info[1].shape[1]
     progs = {
         "stage": (jax.jit(lambda *a: R._stage_chunk(
             *a, hd_hw=(H, W))),
@@ -70,11 +77,11 @@ def main(argv=None):
                     s((T, H // 16, W // 16, 2), jnp.int32),
                     s((args.rows, n_cells, 4)), s((args.rows, n_cells)),
                     s((n_cells, 4)), s((n_cells,)))),
-        "detector": (jax.jit(lambda p, x: D.decode_boxes(
-            D.forward(p, dcfg, x), dcfg)), (params, s((args.rows, H, W)))),
     }
-    for name, (fn, a) in progs.items():
-        m = fn.lower(*a).compile().memory_analysis()
+    lowered = {name: fn.lower(*a) for name, (fn, a) in progs.items()}
+    lowered["detector"] = dispatch
+    for name, low in lowered.items():
+        m = low.compile().memory_analysis()
         print(f"{name}: compiled for v5e; temp "
               f"{m.temp_size_in_bytes / 2**30:.2f} GiB, args "
               f"{m.argument_size_in_bytes / 2**30:.2f} GiB, out "
